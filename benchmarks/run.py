@@ -50,6 +50,9 @@ def main() -> None:
     args = ap.parse_args()
     names = args.only.split(",") if args.only else MODULES
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     print("name,us_per_call,derived")
     failures = 0
     for name in names:

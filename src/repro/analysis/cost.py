@@ -4,9 +4,9 @@ This module holds the machinery behind the cost-model-driven group
 planner (`core/grouping.py` wires it in at ``build_groups``/
 ``grouped_compress`` time):
 
-* :class:`HardwareSpec` — peak FLOPs / HBM / interconnect / VMEM
-  constants per device kind, detected from ``jax.devices()`` instead of
-  the v5e literals that used to live in ``analysis/roofline.py``.
+* :class:`HardwareSpec` — published peak FLOPs / HBM / interconnect /
+  VMEM constants per device kind, looked up by the exact
+  ``device_kind`` of ``jax.devices()[0]``.
 * :class:`GroupPlan` — the per-group decision record: dispatch backend,
   Pallas items-grid tile rows, chunk count, shard mode, and the modeled
   roofline terms that justified them.
@@ -42,13 +42,13 @@ import numpy as np
 class HardwareSpec:
     """Peak-rate constants for one device kind.
 
-    ``match`` is a lowercase substring matched against
-    ``device.device_kind`` by :func:`detect_hardware`.
+    ``kind`` is the exact ``device.device_kind`` string the device
+    reports; :func:`detect_hardware` looks it up in :data:`HARDWARE`.
     """
 
     name: str
-    match: str
-    peak_flops: float      # f32-equivalent FLOP/s per chip
+    kind: str
+    peak_flops: float      # bf16 FLOP/s per chip
     hbm_bw: float          # bytes/s per chip
     link_bw: float         # interconnect bytes/s per chip (one direction)
     vmem_bytes: int        # fast on-chip memory per core
@@ -60,43 +60,45 @@ class HardwareSpec:
         return self.peak_flops / self.hbm_bw
 
 
-# The v5e numbers are the literals `analysis/roofline.py` shipped with;
-# roofline.py now re-exports them from here so dry-run behaviour is
-# unchanged by the refactor.
-TPU_V4 = HardwareSpec("tpu-v4", "tpu v4", 275e12, 1228e9, 75e9,
+# Published per-chip peaks, Google Cloud TPU documentation ("TPU v4",
+# "TPU v5e", "TPU v5p", "TPU v6e" system-architecture pages): bf16
+# FLOP/s, HBM bandwidth and HBM capacity. v5e: 197 TFLOP/s bf16,
+# 819 GB/s, 16 GB. ``link_bw`` is one ICI link in one direction.
+TPU_V4 = HardwareSpec("tpu-v4", "TPU v4", 275e12, 1228e9, 75e9,
                       16 * 2**20, 32 * 2**30)
-TPU_V5E = HardwareSpec("tpu-v5e", "tpu v5e", 197e12, 819e9, 50e9,
+TPU_V5E = HardwareSpec("tpu-v5e", "TPU v5 lite", 197e12, 819e9, 50e9,
                        16 * 2**20, 16 * 2**30)
-TPU_V5P = HardwareSpec("tpu-v5p", "tpu v5", 459e12, 2765e9, 100e9,
+TPU_V5P = HardwareSpec("tpu-v5p", "TPU v5", 459e12, 2765e9, 100e9,
                        16 * 2**20, 95 * 2**30)
-TPU_V6E = HardwareSpec("tpu-v6e", "tpu v6", 918e12, 1640e9, 100e9,
+TPU_V6E = HardwareSpec("tpu-v6e", "TPU v6 lite", 918e12, 1640e9, 100e9,
                        32 * 2**20, 32 * 2**30)
-# CPU numbers are deliberately coarse (one modern server socket); they
-# only need to rank alternatives sensibly, not predict wall clock.
+# Host CPU: coarse numbers (one server socket), used only to rank
+# alternatives off-TPU; they predict no wall clock.
 CPU = HardwareSpec("cpu", "cpu", 1e12, 100e9, 25e9,
                    32 * 2**20, 64 * 2**30)
 
-_KNOWN = (TPU_V4, TPU_V6E, TPU_V5P, TPU_V5E)  # order: most-specific match
+#: exact ``device_kind`` → spec, for every TPU this repo has peaks for
+HARDWARE = {spec.kind: spec for spec in (TPU_V4, TPU_V5E, TPU_V5P,
+                                         TPU_V6E)}
 
 
 def detect_hardware(devices=None) -> HardwareSpec:
     """Map ``jax.devices()`` onto a :class:`HardwareSpec`.
 
-    Unknown TPU kinds default to :data:`TPU_V5E` (the repo's historic
-    dry-run target); anything else falls back to :data:`CPU`.
+    A TPU is looked up by its exact ``device_kind``; a TPU kind missing
+    from :data:`HARDWARE` raises rather than borrowing another chip's
+    peaks. Any other platform gets :data:`CPU`.
     """
     if devices is None:
         devices = jax.devices()
-    if not devices:
+    if not devices or devices[0].platform != "tpu":
         return CPU
-    kind = getattr(devices[0], "device_kind", "cpu").lower()
-    platform = getattr(devices[0], "platform", "cpu").lower()
-    for spec in _KNOWN:
-        if spec.match in kind:
-            return spec
-    if platform == "tpu" or "tpu" in kind:
-        return TPU_V5E
-    return CPU
+    kind = devices[0].device_kind
+    if kind not in HARDWARE:
+        raise ValueError(
+            f"no peaks for TPU device_kind {kind!r}; known kinds: "
+            f"{sorted(HARDWARE)}")
+    return HARDWARE[kind]
 
 
 # ---------------------------------------------------------------------------

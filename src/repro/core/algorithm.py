@@ -35,7 +35,8 @@ import numpy as np
 
 from repro.core import state as lcstate
 from repro.core.grouping import (
-    describe_groups, grouped_compress, grouped_init, solve_task)
+    _constrain_replicated, describe_groups, grouped_compress, grouped_init,
+    solve_task)
 from repro.core.penalty import lc_penalty
 from repro.core.tasks import CompressionTask, check_disjoint, get_path
 from repro.core.views import AsVector
@@ -325,6 +326,11 @@ class LCAlgorithm:
                    - mu * (get_path(params, p).astype(jnp.float32)
                            - ts["a"][p])
                    for p in t.paths}
+            if self.mesh is not None:
+                # λ keeps the replicated layout init gives it: left to
+                # GSPMD it follows Δ(Θ)'s, and every jitted step that
+                # takes the LC state would compile a second time
+                lam = _constrain_replicated(lam, self.mesh)
             new_tasks[t.name] = lcstate.task_state(ts["theta"], lam, ts["a"])
         return lcstate.with_tasks(lc, new_tasks)
 
@@ -333,8 +339,13 @@ class LCAlgorithm:
         return self._mult_step(params, lc)
 
     def set_mu(self, lc, mu: float, k: int) -> dict:
-        return {"tasks": lc["tasks"], "mu": jnp.float32(mu),
-                "k": jnp.int32(k)}
+        # the new scalars go where the old ones live, so the jitted
+        # steps see one input layout across LC steps and compile once
+        return {"tasks": lc["tasks"],
+                "mu": jax.device_put(jnp.float32(mu),
+                                     getattr(lc["mu"], "sharding", None)),
+                "k": jax.device_put(jnp.int32(k),
+                                    getattr(lc["k"], "sharding", None))}
 
     # ------------------------------------------------------------------
     def penalty(self, params, lc) -> jnp.ndarray:

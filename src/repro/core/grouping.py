@@ -618,8 +618,17 @@ def grouped_compress(tasks: Sequence[CompressionTask], xs: dict,
             # fallback) — kernel-dispatched when the scheme opts in,
             # but never sharded (nothing to split across tasks).
             t = group[0]
-            theta = solve_task(t, xs[t.name], thetas[t.name], mu,
-                               backend=backend)
+
+            def solve(x, th, mu_, t=t):
+                return solve_task(t, x, th, mu_, backend=backend)
+
+            if mesh is not None and mesh.devices.size > 1:
+                # GSPMD cannot partition a Pallas (Mosaic) call, even on
+                # replicated operands: every device solves the whole task
+                # inside a manual region instead
+                solve = shard_map(solve, mesh, in_specs=(P(), P(), P()),
+                                  out_specs=P())
+            theta = solve(xs[t.name], thetas[t.name], mu)
             out[t.name] = (theta, t.scheme_decompress(theta))
             continue
 

@@ -60,10 +60,3 @@ def ready_probe(lc: dict):
     """
     return jax.tree_util.tree_leaves(lc["tasks"])[-1]
 
-
-def probe_is_ready(probe) -> bool:
-    """``probe.is_ready()`` with a conservative fallback: jax < 0.4.10
-    arrays have no ``is_ready`` — report not-ready and let the caller's
-    deadline (swap_after / L-step end) force the block instead."""
-    is_ready = getattr(probe, "is_ready", None)
-    return bool(is_ready()) if is_ready is not None else False

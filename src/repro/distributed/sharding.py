@@ -21,18 +21,11 @@ import threading
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.7: public API, kwarg `check_vma`
-    _shard_map = jax.shard_map
-    _SM_CHECK_KW = "check_vma"
-except AttributeError:  # jax 0.4.x: experimental module, kwarg `check_rep`
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_CHECK_KW = "check_rep"
-
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """Version-tolerant ``shard_map`` (jax 0.4.x ↔ ≥0.7 signature drift)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_SM_CHECK_KW: check_vma})
+    """``jax.shard_map`` with the repo's default ``check_vma=False``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 # candidates: tuples of mesh axis names (joint sharding) tried in order;
 # () means replicate.

@@ -105,8 +105,9 @@ def resolve_backend(requested: str | None = "auto") -> str | None:
     ``None``/``"off"`` disables kernel dispatch entirely (pure vmapped
     scheme programs, κ static). ``"auto"`` is ``pallas`` on TPU and
     ``jnp`` elsewhere. ``"pallas"`` without a TPU degrades to
-    ``interpret`` — the kernel path, emulated — so tests and CI
-    exercise the same program the TPU compiles.
+    ``interpret`` — the kernel path, emulated — so CPU tests and CI
+    exercise the same program the TPU compiles; on a TPU it is always
+    the compiled kernel.
     """
     if requested is None or requested == "off":
         return None

@@ -19,8 +19,9 @@ Two entry points share the kernel body:
 * :func:`kmeans_assign_moments_batched` — a packed *group* of items
   (the grouped C step's stacked leading axis), grid
   ``(items, n_tiles)``. Each item brings its own VMEM-resident codebook
-  (BlockSpec ``(1, K)`` indexed by the item coordinate) and its own
-  moment accumulators; the tile coordinate is the fast axis, so the
+  and its own moment accumulators, held as ``(I, 1, K)`` arrays so that
+  a per-item block ``(1, 1, K)`` spans the full last two dimensions (the
+  TPU's (8, 128) tiling rule); the tile coordinate is the fast axis, so the
   per-item accumulation runs grid-sequentially exactly like the
   unbatched kernel, and one ``pallas_call`` solves the whole group
   instead of vmapping the jnp solver.
@@ -100,8 +101,8 @@ def _batched_kernel(w_ref, cb_ref, assign_ref, sums_ref, counts_ref,
                     *, k: int):
     tile = pl.program_id(1)                           # fast axis: tiles
     w = w_ref[0]                                      # (ROWS, LANES) f32
-    cb = cb_ref[0]                                    # (K,) f32
-    d = (w[:, :, None] - cb[None, None, :]) ** 2      # (ROWS, LANES, K)
+    cb = cb_ref[0]                                    # (1, K) f32
+    d = (w[:, :, None] - cb[0][None, None, :]) ** 2   # (ROWS, LANES, K)
     assign = jnp.argmin(d, axis=-1).astype(jnp.int32)
     assign_ref[0] = assign
     onehot = (assign[:, :, None]
@@ -114,13 +115,13 @@ def _batched_kernel(w_ref, cb_ref, assign_ref, sums_ref, counts_ref,
     # is row-major (tile fastest), so `tile == 0` re-inits per item
     @pl.when(tile == 0)
     def _init():
-        sums_ref[...] = part_sums
-        counts_ref[...] = part_counts
+        sums_ref[0] = part_sums
+        counts_ref[0] = part_counts
 
     @pl.when(tile != 0)
     def _accum():
-        sums_ref[...] += part_sums
-        counts_ref[...] += part_counts
+        sums_ref[0] += part_sums
+        counts_ref[0] += part_counts
 
 
 @partial(jax.jit, static_argnames=("interpret", "block_rows"))
@@ -144,26 +145,27 @@ def kmeans_assign_moments_batched(w: jnp.ndarray, codebooks: jnp.ndarray,
     assert p % tile == 0, f"pad to a multiple of {tile} in ops.py"
     n_tiles = p // tile
     w3 = w.astype(jnp.float32).reshape(n_items, n_tiles * rows, LANES)
-    cb2 = codebooks.astype(jnp.float32).reshape(n_items, k)
+    cb3 = codebooks.astype(jnp.float32).reshape(n_items, 1, k)
 
-    assign3, sums2, counts2 = pl.pallas_call(
+    assign3, sums3, counts3 = pl.pallas_call(
         partial(_batched_kernel, k=k),
         grid=(n_items, n_tiles),
         in_specs=[
             pl.BlockSpec((1, rows, LANES), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),  # per-item VMEM
+            pl.BlockSpec((1, 1, k), lambda i, j: (i, 0, 0)),  # per item
         ],
         out_specs=[
             pl.BlockSpec((1, rows, LANES), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),  # per-item accum
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, k), lambda i, j: (i, 0, 0)),  # accum
+            pl.BlockSpec((1, 1, k), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_items, n_tiles * rows, LANES),
                                  jnp.int32),
-            jax.ShapeDtypeStruct((n_items, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_items, k), jnp.float32),
+            jax.ShapeDtypeStruct((n_items, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((n_items, 1, k), jnp.float32),
         ],
         interpret=interpret,
-    )(w3, cb2)
-    return assign3.reshape(n_items, p), sums2, counts2
+    )(w3, cb3)
+    return (assign3.reshape(n_items, p), sums3.reshape(n_items, k),
+            counts3.reshape(n_items, k))

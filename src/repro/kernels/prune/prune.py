@@ -12,7 +12,9 @@ Batched variants (:func:`count_above_batched`,
 :func:`mask_apply_batched`) add an **items grid dimension** for the
 grouped C step: grid ``(items, n_tiles)``, a per-item threshold block in
 VMEM, a per-item count accumulator re-initialized when the (fast) tile
-coordinate wraps. The threshold — and therefore κ, which the bisection
+coordinate wraps. Per-item scalars are held as ``(I, 1, 1)`` arrays so
+that their ``(1, 1, 1)`` blocks span the full last two dimensions (the
+TPU's (8, 128) tiling rule). The threshold — and therefore κ, which the bisection
 driver in ops.py compares the counts against — is a *traced per-item
 operand*, which is what lets tasks that differ only in κ share one
 kernel launch (mixed-κ grouping).
@@ -104,22 +106,22 @@ def mask_apply(w: jnp.ndarray, t: jnp.ndarray, interpret: bool = True):
 def _count_batched_kernel(w_ref, t_ref, out_ref, *, strict: bool):
     tile = pl.program_id(1)                      # fast axis: tiles
     w = w_ref[0]                                 # (ROWS, LANES)
-    t = t_ref[0, 0]                              # this item's threshold
+    t = t_ref[0, 0, 0]                           # this item's threshold
     keep = jnp.abs(w) > t if strict else jnp.abs(w) >= t
     c = jnp.sum(keep.astype(jnp.float32))[None, None]
 
     @pl.when(tile == 0)
     def _init():
-        out_ref[...] = c
+        out_ref[0] = c
 
     @pl.when(tile != 0)
     def _accum():
-        out_ref[...] += c
+        out_ref[0] += c
 
 
 def _mask_batched_kernel(w_ref, t_ref, out_ref, *, strict: bool):
     w = w_ref[0]
-    t = t_ref[0, 0]
+    t = t_ref[0, 0, 0]
     keep = jnp.abs(w) > t if strict else jnp.abs(w) >= t
     out_ref[0] = jnp.where(keep, w, 0.0)
 
@@ -148,13 +150,13 @@ def count_above_batched(w: jnp.ndarray, t: jnp.ndarray,
         grid=(n_items, n_tiles),
         in_specs=[
             pl.BlockSpec((1, rows, LANES), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_items, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_items, 1, 1), jnp.float32),
         interpret=interpret,
-    )(w3, t.reshape(n_items, 1).astype(jnp.float32))
-    return out[:, 0]
+    )(w3, t.reshape(n_items, 1, 1).astype(jnp.float32))
+    return out[:, 0, 0]
 
 
 @partial(jax.jit, static_argnames=("interpret", "strict", "block_rows"))
@@ -171,11 +173,11 @@ def mask_apply_batched(w: jnp.ndarray, t: jnp.ndarray,
         grid=(n_items, n_tiles),
         in_specs=[
             pl.BlockSpec((1, rows, LANES), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, rows, LANES), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct(
             (n_items, n_tiles * rows, LANES), jnp.float32),
         interpret=interpret,
-    )(w3, t.reshape(n_items, 1).astype(jnp.float32))
+    )(w3, t.reshape(n_items, 1, 1).astype(jnp.float32))
     return out.reshape(n_items, p)

@@ -87,10 +87,12 @@ def _packed_kernel(xe_ref, xo_ref, packed_ref, cb_ref, y_ref, *,
 
     xe = xe_ref[...].astype(jnp.float32)             # (bm, bk2)
     xo = xo_ref[...].astype(jnp.float32)             # (bm, bk2)
-    packed = packed_ref[...]                          # (bk2, bn) uint8
+    # unpack in int32: Mosaic cannot relayout the i1 masks of 8-bit
+    # compares, and the 32-bit lanes match the f32 select below
+    packed = packed_ref[...].astype(jnp.int32)        # (bk2, bn)
     cb = cb_ref[...]                                  # (1, C)
-    lo = packed & jnp.uint8(0x0F)
-    hi = packed >> jnp.uint8(4)
+    lo = packed & 0x0F
+    hi = packed >> 4
     w_lo = jnp.zeros(packed.shape, jnp.float32)
     w_hi = jnp.zeros(packed.shape, jnp.float32)
     for c in range(n_codes):

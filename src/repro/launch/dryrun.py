@@ -114,8 +114,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
 
         print(compiled.memory_analysis())
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax ≤ 0.4.x: one dict per module
-            ca = ca[0] if ca else {}
         print({k: v for k, v in ca.items()
                if k in ("flops", "bytes accessed")})
         terms = rl.analyze(compiled, arch=arch, shape=shape,

@@ -2,10 +2,23 @@
 
 A function, not a module-level constant, so importing this module never
 touches jax device state (the dry-run sets XLA_FLAGS before first init).
+
+Every mesh is built by :func:`make_mesh`, with ``Auto`` axis types: the
+sharding rules (``distributed/sharding.py``) place arrays with
+``with_sharding_constraint``, which accepts only ``Auto`` axes, and
+``jax.make_mesh`` defaults to ``Explicit`` ones.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,12 +30,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh for CPU tests/examples (1 device)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_cstep_mesh(n_data: int | None = None):
@@ -37,4 +50,4 @@ def make_cstep_mesh(n_data: int | None = None):
     and the sharded path becomes an annotated no-op.
     """
     n = n_data if n_data is not None else len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))

@@ -18,19 +18,25 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.launch.steps import lc_param_paths
 from repro.models.transformer import init_params
 from repro.runtime.server import (
-    Request, Server, ServingEngine, load_compressed_for_serving,
-    quantize_params_for_serving, serving_bits)
+    Request, Server, ServingEngine, densified_for_serving,
+    load_compressed_for_serving, quantize_params_for_serving,
+    serving_bits)
 
 FORMS = ("dense", "quant4", "quant8", "lowrank", "sparse")
 
 
 def compress_for_form(cfg, params, form: str):
-    """Bridge the model's FFN matrices into one serving form via a real
-    LC state (direct compression init)."""
+    """Bridge the model's 2-D matrices into one serving form via a real
+    LC state (direct compression init).
+
+    Returns ``(serving, reference)``: the params in compressed form, and
+    the same model with every compressed matrix densified to Δ(Θ), the
+    parity reference."""
     from repro.core import AsIs, AsVector, CompressionTask, LCAlgorithm
     from repro.core.schemes import (
         AdaptiveQuantization, ConstraintL0Pruning, LowRank)
@@ -65,7 +71,7 @@ def compress_for_form(cfg, params, form: str):
     kinds = sorted({v.split("(")[0] for f in report.values()
                     for v in f.values()})
     print(f"bridged {n} matrices to {form}: forms={kinds}")
-    return serving
+    return serving, densified_for_serving(params, state, algo.tasks)
 
 
 def run_engine(cfg, params, args):
@@ -132,6 +138,7 @@ def main():
                     help="engine trace length")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)
@@ -149,7 +156,7 @@ def main():
               f"{dense / 8e6:.1f} MB → {comp / 8e6:.1f} MB "
               f"({dense / comp:.1f}× smaller)")
     elif args.form != "dense":
-        params = compress_for_form(cfg, params, args.form)
+        params, _ = compress_for_form(cfg, params, args.form)
 
     if args.engine:
         run_engine(cfg, params, args)

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import logging
+import re
 
 import jax
 
@@ -20,23 +21,56 @@ from repro.core import (
     AsStacked, AsVector, CompressionTask, LCAlgorithm,
     exponential_mu_schedule)
 from repro.core.schemes import AdaptiveQuantization, ConstraintL0Pruning
+from repro.core.tasks import flatten_params
 from repro.data import TokenStream, embedding_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
+from repro.models.transformer import init_params
 from repro.runtime import FaultInjector, LCTrainer, TrainerConfig
+
+#: stacked projections the top-κ task prunes (attention and gated FFN)
+PRUNED = ("w_gate", "w_up", "w_down", "wq", "wk", "wv", "wo")
+#: the remaining stacked projections (mixer in/out and xLSTM projections)
+PROJECTIONS = ("in_proj", "out_proj", "up_proj", "down_proj", "w")
+COMPRESSIONS = ("quantize", "prune", "quantize+prune")
+#: fraction of the pruned stacks' weights the top-κ task keeps
+PRUNE_KEEP = 0.1
+
+
+def _stacks(names) -> str:
+    return rf"stages/.*/({'|'.join(names)})$"
 
 
 def default_tasks(cfg, compression: str = "quantize"):
-    """The flagship per-arch compression tasks: per-layer adaptive
-    codebooks on the scanned stacks (AsStacked ⇒ vmapped C steps)."""
-    if compression == "quantize":
-        return [CompressionTask(
-            "quantize-stacks", r"stages/.*/(w_gate|w_up|w_down|wq|wk|wv|wo|in_proj|out_proj|up_proj|down_proj|w)$",
-            AsStacked("vector"), AdaptiveQuantization(k=16, iters=10))]
-    if compression == "prune":
-        return [CompressionTask(
-            "prune-all", r"stages/.*/(w_gate|w_up|w_down|wq|wk|wv|wo)$",
-            AsVector(), ConstraintL0Pruning(kappa=0))]  # κ set by caller
-    raise ValueError(compression)
+    """The flagship per-arch compression tasks.
+
+    * ``"quantize"``: per-layer adaptive 4-bit codebooks on every
+      stacked projection (AsStacked ⇒ one item per layer).
+    * ``"prune"``: one ℓ0 budget over the attention and gated-FFN
+      stacks, keeping the :data:`PRUNE_KEEP` fraction of largest
+      weights.
+    * ``"quantize+prune"``: the prune task, plus 4-bit codebooks on the
+      other stacked projections (tasks must not share parameters).
+    """
+    if compression not in COMPRESSIONS:
+        raise ValueError(compression)
+    tasks = []
+    if "prune" in compression:
+        shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+                                jax.random.PRNGKey(0))
+        rx = re.compile(_stacks(PRUNED))
+        total = sum(leaf.size for p, leaf in flatten_params(shapes).items()
+                    if rx.search(p))
+        tasks.append(CompressionTask(
+            "prune-stacks", _stacks(PRUNED), AsVector(),
+            ConstraintL0Pruning(kappa=max(1, int(PRUNE_KEEP * total)))))
+    if "quantize" in compression:
+        names = PROJECTIONS if "prune" in compression \
+            else PRUNED + PROJECTIONS
+        tasks.append(CompressionTask(
+            "quantize-stacks", _stacks(names),
+            AsStacked("vector"), AdaptiveQuantization(k=16, iters=10)))
+    return tasks
 
 
 def main():
@@ -54,6 +88,7 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)

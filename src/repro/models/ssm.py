@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.distributed.sharding import constrain
 from repro.models.attention import read_layer_cache, write_layer_cache
-from repro.models.layers import apply_w, dense_init, rms_norm
+from repro.models.layers import apply_w, dense_init, rms_norm, wload
 
 
 # ======================================================================
@@ -107,7 +107,7 @@ def mamba_forward(params, x, cfg, spec, positions, chunk: int = 128,
     xz = apply_w(x, params["in_proj"], dt_)
     xi, z = jnp.split(xz, 2, axis=-1)
     xi = constrain(xi, ("batch", "seq", "inner"))
-    xi, conv_tail = _causal_conv(xi, params["conv_w"].astype(dt_),
+    xi, conv_tail = _causal_conv(xi, wload(params["conv_w"], dt_),
                                  params["conv_b"].astype(dt_))
     xi = jax.nn.silu(xi)
 
@@ -173,7 +173,7 @@ def mamba_decode(params, x, cache, pos, cfg, spec, layer_idx=None):
     xz = apply_w(x, params["in_proj"], dt_)
     xi, z = jnp.split(xz, 2, axis=-1)
     xi, conv_state = _causal_conv(
-        xi, params["conv_w"].astype(dt_), params["conv_b"].astype(dt_),
+        xi, wload(params["conv_w"], dt_), params["conv_b"].astype(dt_),
         state=cache["conv"])
     xi = jax.nn.silu(xi)[:, 0]                       # (B, di)
 
@@ -255,7 +255,7 @@ def mlstm_forward(params, x, cfg, spec, positions, return_cache=False):
 
     xz = apply_w(x, params["up_proj"], dt_)
     xm, z = jnp.split(xz, 2, axis=-1)
-    xc, conv_tail = _causal_conv(xm, params["conv_w"].astype(dt_),
+    xc, conv_tail = _causal_conv(xm, wload(params["conv_w"], dt_),
                                  params["conv_b"].astype(dt_))
     xc = jax.nn.silu(xc)
     q = apply_w(xc, params["wq"], dt_).reshape(b, s, hn, dh)
@@ -356,7 +356,7 @@ def mlstm_decode(params, x, cache, pos, cfg, spec, layer_idx=None):
     xz = apply_w(x, params["up_proj"], dt_)
     xm, z = jnp.split(xz, 2, axis=-1)
     xc, conv_state = _causal_conv(
-        xm, params["conv_w"].astype(dt_), params["conv_b"].astype(dt_),
+        xm, wload(params["conv_w"], dt_), params["conv_b"].astype(dt_),
         state=cache["conv"])
     xc = jax.nn.silu(xc)[:, 0]
     xm = xm[:, 0]
@@ -471,8 +471,11 @@ def slstm_forward(params, x, cfg, spec, positions, return_cache=False):
 
 def init_slstm_cache(cfg, spec, batch: int, max_len: int, dtype) -> dict:
     di, _, _ = _slstm_dims(cfg)
-    z = jnp.zeros((batch, di), jnp.float32)
-    return {"c": z, "n": z, "h": z,
+    # one array per leaf: the serving engine donates the cache, and a
+    # buffer shared by two leaves cannot be donated twice
+    return {"c": jnp.zeros((batch, di), jnp.float32),
+            "n": jnp.zeros((batch, di), jnp.float32),
+            "h": jnp.zeros((batch, di), jnp.float32),
             "m": jnp.full((batch, di), -30.0, jnp.float32)}
 
 

@@ -5,6 +5,8 @@ Designed for 1000+-node behavior, exercised here via fault injection:
 * ``RetryPolicy`` — transient step failures (preempted host, flaky ICI
   link surfacing as RuntimeError) retry with exponential backoff; after
   ``max_retries`` the trainer falls back to restore-from-checkpoint.
+  A compile error or an out-of-memory error is not transient
+  (:func:`is_transient`): it surfaces at once.
 * ``StragglerMonitor`` — per-step wall times vs a rolling median; a step
   slower than ``factor``× median marks a straggler. The trainer's
   response is pluggable (log / re-shard via elastic reload / evict).
@@ -16,6 +18,20 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass
+
+#: markers of XLA / Mosaic errors that a retry or a restore cannot fix:
+#: the program does not compile, or does not fit the device
+_PERMANENT_MARKERS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT",
+                      "UNIMPLEMENTED", "Mosaic failed to compile")
+
+
+def is_transient(e: BaseException) -> bool:
+    """Whether a step failure may succeed when run again."""
+    if not isinstance(e, (RuntimeError, OSError)) or \
+            isinstance(e, NotImplementedError):
+        return False
+    msg = str(e)
+    return not any(m in msg for m in _PERMANENT_MARKERS)
 
 
 @dataclass
@@ -30,9 +46,9 @@ class RetryPolicy:
         for attempt in range(self.max_retries + 1):
             try:
                 return fn(*args, **kwargs)
-            except (RuntimeError, OSError) as e:  # transient class
+            except (RuntimeError, OSError) as e:
                 last = e
-                if attempt == self.max_retries:
+                if attempt == self.max_retries or not is_transient(e):
                     raise
                 if on_retry:
                     on_retry(attempt, e)

@@ -36,18 +36,19 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.core.algorithm import LCAlgorithm
-from repro.core.state import probe_is_ready, ready_probe
+from repro.core.state import ready_probe
 from repro.core.tasks import get_path
 from repro.data.pipeline import Prefetcher
 from repro.distributed.sharding import use_mesh
 from repro.launch.steps import make_train_step, stable_lc_refs
 from repro.optim import AdamW
 from repro.runtime.fault_tolerance import (
-    FaultInjector, RetryPolicy, StragglerMonitor)
+    FaultInjector, RetryPolicy, StragglerMonitor, is_transient)
 
 log = logging.getLogger("repro.trainer")
 
@@ -137,6 +138,11 @@ class LCTrainer:
         with use_mesh(self.mesh):
             state = init_train_state(key, self.cfg, self.optimizer,
                                      with_lc=True)
+        if self.mesh is not None:
+            # commit the state to the mesh, replicated: left uncommitted,
+            # the train step's first call compiles a program for an
+            # unplaced state that no later call runs
+            state = jax.device_put(state, NamedSharding(self.mesh, P()))
         # attach real LC state (Θ, λ) from the algorithm
         lc_state = self.lc.init(state["params"])
         state["lc"] = self._refs_from_lc(state["params"], lc_state)
@@ -222,7 +228,9 @@ class LCTrainer:
                     self._one_step, state, step,
                     on_retry=lambda a, e: log.warning(
                         "step %d retry %d: %s", step, a, e))
-            except RuntimeError:
+            except RuntimeError as e:
+                if not is_transient(e):
+                    raise
                 if self.ckpt:
                     # let an in-flight background save commit (and its
                     # errors surface) before deciding whether/where to
@@ -355,7 +363,7 @@ class LCTrainer:
                 return st
             deadline = swap_after is not None and done >= swap_after
             if deadline or (swap_after is None
-                            and probe_is_ready(self._pending["probe"])):
+                            and self._pending["probe"].is_ready()):
                 st = self._apply_pending(st, block=deadline, done=done)
             return st
 

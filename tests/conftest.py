@@ -7,78 +7,3 @@ import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
 
-
-# ----------------------------------------------------------------------
-# hypothesis shim: the property tests degrade to a deterministic sweep of
-# boundary + pseudorandom examples when hypothesis isn't installed (it is
-# listed in requirements-dev.txt; CI installs the real thing).
-# ----------------------------------------------------------------------
-def _install_hypothesis_shim():
-    import random
-    import sys
-    import types
-    import zlib
-
-    _SHIM_CAP = 8  # examples per property when running on the shim
-
-    class _Strategy:
-        def __init__(self, boundary, draw):
-            self._boundary = list(boundary)
-            self._draw = draw
-
-        def examples(self, n, rng):
-            vals = list(self._boundary)
-            while len(vals) < n:
-                vals.append(self._draw(rng))
-            return vals[:n]
-
-    def integers(min_value=0, max_value=1 << 30):
-        return _Strategy([min_value, max_value],
-                         lambda rng: rng.randint(min_value, max_value))
-
-    def floats(min_value=0.0, max_value=1.0, **_kw):
-        return _Strategy([min_value, max_value],
-                         lambda rng: rng.uniform(min_value, max_value))
-
-    def sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(elements[:1],
-                         lambda rng: rng.choice(elements))
-
-    def given(*strategies):
-        def deco(fn):
-            def wrapper():
-                n = min(getattr(wrapper, "_shim_max_examples", _SHIM_CAP),
-                        _SHIM_CAP)
-                rng = random.Random(zlib.crc32(fn.__name__.encode()))
-                cols = [s.examples(n, rng) for s in strategies]
-                for vals in zip(*cols):
-                    fn(*vals)
-            # deliberately NOT functools.wraps: pytest must see a
-            # zero-arg test, not the original's strategy parameters
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            wrapper._shim_max_examples = _SHIM_CAP
-            return wrapper
-        return deco
-
-    def settings(max_examples=_SHIM_CAP, deadline=None, **_kw):
-        def deco(fn):
-            fn._shim_max_examples = max_examples
-            return fn
-        return deco
-
-    hyp = types.ModuleType("hypothesis")
-    st = types.ModuleType("hypothesis.strategies")
-    st.integers, st.floats, st.sampled_from = integers, floats, sampled_from
-    hyp.given, hyp.settings, hyp.strategies = given, settings, st
-    hyp.__version__ = "0.0-shim"
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _install_hypothesis_shim()

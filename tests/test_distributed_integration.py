@@ -32,6 +32,7 @@ COMMON = """
 import jax, jax.numpy as jnp, numpy as np, json
 from repro.configs import get_config, reduced_config
 from repro.distributed.sharding import use_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step, init_train_state
 from repro.launch import inputs as specs_mod
 """
@@ -56,7 +57,7 @@ batch = {
 }
 losses = {}
 for shape, axes in [((1,1),("data","model")), ((2,4),("data","model"))]:
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     with use_mesh(mesh):
         state = init_train_state(key, cfg)
         step = jax.jit(make_train_step(cfg))
@@ -76,7 +77,7 @@ def test_multipod_mesh_step_runs():
     script = COMMON + """
 cfg = reduced_config(get_config("mixtral-8x7b"))
 key = jax.random.PRNGKey(0)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 batch = {
   "inputs": jax.random.randint(jax.random.fold_in(key,1), (8, 16), 0, cfg.vocab_size),
   "labels": jax.random.randint(jax.random.fold_in(key,2), (8, 16), 0, cfg.vocab_size),
@@ -91,6 +92,43 @@ print("ok", float(metrics["loss"]))
 """
     out = _run(script)
     assert "ok" in out
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_mesh_lc_trainer_compiles_each_step_once(devices):
+    """Two LC steps of ``LCTrainer`` on a data mesh: the C step, the
+    multiplier step and the distortion monitor each compile once. A
+    multiplier λ left in GSPMD's layout, or a new μ placed apart from
+    the state's, made every LC step compile them again. On one device
+    the train step compiles once too; on four, GSPMD returns some
+    optimizer moments in a layout of its own, which costs one more."""
+    script = """
+import json
+import jax
+from repro.configs import get_config, reduced_config
+from repro.core import LCAlgorithm, exponential_mu_schedule
+from repro.data import TokenStream
+from repro.launch.mesh import make_cstep_mesh
+from repro.launch.train import default_tasks
+from repro.runtime import LCTrainer, TrainerConfig
+cfg = reduced_config(get_config("xlstm-125m"))
+lc = LCAlgorithm(default_tasks(cfg, "quantize+prune"),
+                 exponential_mu_schedule(1e-4, 1.2, 2))
+tr = LCTrainer(cfg, lc, TokenStream(cfg.vocab_size, 4, 16),
+               mesh=make_cstep_mesh(), tcfg=TrainerConfig(steps_per_l=2))
+tr.run(jax.random.PRNGKey(0))
+steps = {n: getattr(lc, "_" + n)
+         for n in ("c_step", "mult_step", "shifted_distortion")}
+steps["train_step"] = tr._train_step
+print(json.dumps({n: f._cache_size() for n, f in steps.items()}))
+"""
+    out = _run(script, devices=devices)
+    compiles = json.loads(out.strip().splitlines()[-1])
+    once = {"c_step", "mult_step", "shifted_distortion"}
+    if devices == 1:
+        once.add("train_step")
+    assert {n: compiles[n] for n in once} == dict.fromkeys(once, 1), \
+        compiles
 
 
 def test_dryrun_cell_subprocess():

@@ -496,6 +496,7 @@ def test_lowrank_gspmd_multidevice_subprocess():
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import AsIs, CompressionTask, LCAlgorithm
 from repro.core.schemes import LowRank
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 
 assert jax.device_count() == 4, jax.device_count()
@@ -512,7 +513,7 @@ def tasks():
     return [CompressionTask(f"lr{i}", f"^l{i}$", AsIs(), LowRank(r))
             for i, r in enumerate(ranks)]
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 lcm = LCAlgorithm(tasks(), [1e-2], cstep_backend="jnp", mesh=mesh)
 lc0 = LCAlgorithm(tasks(), [1e-2], cstep_backend="jnp")
 (g,) = lcm.group_summary(params)

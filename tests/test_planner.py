@@ -15,6 +15,7 @@ from repro.core.grouping import (
     _plan_multi_group, _task_solver, compile_group, describe_groups,
     grouped_compress)
 from repro.core.schemes import AdaptiveQuantization, ConstraintL0Pruning
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.PRNGKey(7)
 
@@ -166,7 +167,7 @@ def test_plan_key_sensitivity():
     assert base != cost.plan_key(("quant", 4), 4, (sds,), None, "jnp")
     other = jax.ShapeDtypeStruct((4, 32), jnp.float32)
     assert base != cost.plan_key(("quant", 4), 4, (other,), None, "auto")
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
+    mesh = make_mesh((1,), ("data",))
     assert base != cost.plan_key(("quant", 4), 4, (sds,), mesh, "auto")
 
 
@@ -246,6 +247,23 @@ def test_detect_hardware_and_tiles():
     tiles = cost.gemm_tiles(4, 2048, 512, packed=True)
     assert set(tiles) == {"block_m", "block_n", "block_k"}
     assert all(v >= 8 for v in tiles.values())
+
+
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_detect_hardware_exact_kind():
+    """A v5e reports "TPU v5 lite" and gets v5e's published peaks, not
+    v5p's; an unknown TPU kind is an error, never a default."""
+    hw = cost.detect_hardware([_Dev("tpu", "TPU v5 lite")])
+    assert hw is cost.TPU_V5E
+    assert (hw.peak_flops, hw.hbm_bw) == (197e12, 819e9)
+    assert cost.detect_hardware([_Dev("tpu", "TPU v5")]) is cost.TPU_V5P
+    assert cost.detect_hardware([_Dev("cpu", "cpu")]) is cost.CPU
+    with pytest.raises(ValueError, match="TPU v99"):
+        cost.detect_hardware([_Dev("tpu", "TPU v99")])
 
 
 def test_chunk_and_backend_choosers():

@@ -307,3 +307,33 @@ def test_hbm_accounting_orders_forms():
     w = params["stages"]["s0"]["pos0"]["ffn"]["w_gate"]
     assert cforms.weight_form_bytes(
         qs["stages"]["s0"]["pos0"]["ffn"]["w_gate"]) < w.size
+
+
+def test_xlstm_quant4_engine_reuses_slots():
+    """The quant4 form of xLSTM keeps per-layer (unstacked) caches, so the
+    sLSTM cache leaves reach the engine's donated reset program as they
+    were made: each must be its own buffer. More requests than slots
+    force every slot to be reset and reused."""
+    from repro.configs import get_config, reduced_config
+    from repro.launch.serve import compress_for_form
+    from repro.models.ssm import init_slstm_cache
+
+    cfg = reduced_config(get_config("xlstm-125m")).with_(pattern_reps=1)
+    spec = next(s for s in cfg.pattern if s.mixer == "slstm")
+    leaves = init_slstm_cache(cfg, spec, 2, 8, jnp.float32).values()
+    assert len({id(x) for x in leaves}) == len(leaves)
+
+    params = init_params(KP, cfg)
+    serving, _ = compress_for_form(cfg, params, "quant4")
+    rng = np.random.default_rng(1)
+    reqs = [Request(id=i,
+                    prompt=rng.integers(1, cfg.vocab_size, size=s)
+                    .astype(np.int32),
+                    max_new=3, arrival=0.0)
+            for i, s in enumerate((4, 9, 6, 11, 5))]
+    eng = ServingEngine(cfg, serving, slots=2, max_len=24, prefill_chunk=4)
+    out = eng.run(reqs)
+    assert not out["rejected"]
+    assert sorted(f.id for f in out["finished"]) == list(range(len(reqs)))
+    assert all(len(f.tokens) == 3 for f in out["finished"])
+    assert all(n == 1 for n in eng.trace_counts.values()), eng.trace_counts

@@ -21,6 +21,7 @@ from repro.core import AsVector, CompressionTask, LCAlgorithm
 from repro.core.grouping import describe_groups
 from repro.core.schemes import AdaptiveQuantization, ConstraintL0Pruning
 from repro.distributed.sharding import items_partition
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -127,7 +128,7 @@ def test_group_summary_ignores_mesh_on_pertask_path():
     tasks = [CompressionTask(f"pr{i}", f"^l{i}$", AsVector(),
                              ConstraintL0Pruning(kappa=8))
              for i in range(4)]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     lc = LCAlgorithm(tasks, [1e-2], group_tasks=False, mesh=mesh)
     (g,) = lc.group_summary(params)
     assert g["spec"] is None and g["padding"] == 0
@@ -139,7 +140,7 @@ def test_group_summary_threads_mesh():
     tasks = [CompressionTask(f"pr{i}", f"^l{i}$", AsVector(),
                              ConstraintL0Pruning(kappa=8))
              for i in range(4)]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     lc = LCAlgorithm(tasks, [1e-2], mesh=mesh)
     (g,) = lc.group_summary(params)
     assert g["spec"] == P("data") and g["padding"] == 0
@@ -179,7 +180,7 @@ def _quant_prune_setup():
 
 def test_one_device_mesh_matches_mesh_none():
     params, tasks = _quant_prune_setup()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     lcm = LCAlgorithm(tasks(), [1e-2, 1.5e-2], mesh=mesh)
     lc0 = LCAlgorithm(tasks(), [1e-2, 1.5e-2])
     sm, s0 = lcm.init(params), lc0.init(params)
@@ -197,7 +198,7 @@ def test_set_mesh_rebuilds_jitted_c_step():
     st = lc.init(params)
     st1 = lc.c_step(params, st)                     # compiled without mesh
     before = lc._c_step
-    lc.set_mesh(jax.make_mesh((1, 1), ("data", "model")))
+    lc.set_mesh(make_mesh((1, 1), ("data", "model")))
     assert lc._c_step is not before                 # stale cache dropped
     st2 = lc.c_step(params, st)
     _state_equal(st1, st2)
@@ -249,6 +250,7 @@ from repro.core import (AsIs, AsStacked, AsVector, CompressionTask,
                         LCAlgorithm)
 from repro.core.schemes import (AdaptiveQuantization, ConstraintL0Pruning,
                                 LowRank)
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 
 class OptOutPrune(ConstraintL0Pruning):
@@ -290,7 +292,7 @@ def tasks():
         + [CompressionTask("ex", r"exotic/p$", AsVector(),
                            OptOutPrune(kappa=64))])
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 lcm = LCAlgorithm(tasks(), [1e-2] * 3, mesh=mesh)
 lc0 = LCAlgorithm(tasks(), [1e-2] * 3)
 
@@ -330,6 +332,7 @@ def test_sharded_cstep_multipod_rule():
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import AsVector, CompressionTask, LCAlgorithm
 from repro.core.schemes import ConstraintL0Pruning
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 
 KEY = jax.random.PRNGKey(0)
@@ -339,7 +342,7 @@ def tasks():
     return [CompressionTask(f"pr{i}", f"^l{i}$", AsVector(),
                             ConstraintL0Pruning(kappa=32))
             for i in range(6)]
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = {"items": [("pod", "data"), ("data",), ()]}
 lcm = LCAlgorithm(tasks(), [1e-2], mesh=mesh, sharding_rules=rules)
 lc0 = LCAlgorithm(tasks(), [1e-2])

@@ -14,6 +14,7 @@ from repro.data import TokenStream, teacher_classification
 from repro.distributed.compression_comm import (
     compress_tree, ef_compress, init_ef)
 from repro.distributed.sharding import resolve_spec
+from repro.launch.mesh import make_mesh
 from repro.runtime.fault_tolerance import (
     FaultInjector, RetryPolicy, StragglerMonitor)
 
@@ -116,7 +117,7 @@ def test_checkpoint_elastic_resharding(tmp_path):
     mgr = CheckpointManager(str(tmp_path), async_save=False)
     st = _state(KEY)
     mgr.save(st, 1)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = jax.tree_util.tree_map(
         lambda _: NamedSharding(mesh, P()), st)
     restored, _ = mgr.restore(st, shardings=sh)
@@ -145,6 +146,25 @@ def test_retry_policy_exhausts():
     rp = RetryPolicy(max_retries=2, backoff_s=0.001)
     with pytest.raises(RuntimeError):
         rp.run(lambda: inj.maybe_fail(0))
+
+
+@pytest.mark.parametrize("msg", [
+    "RESOURCE_EXHAUSTED: Error allocating device buffer",
+    "INTERNAL: Mosaic failed to compile TPU kernel: Invalid relayout",
+])
+def test_retry_policy_surfaces_permanent_errors(msg):
+    """Out-of-memory and compile errors are not transient: the first
+    one is raised, with no retry."""
+    calls = []
+
+    def step():
+        calls.append(1)
+        raise RuntimeError(msg)
+
+    rp = RetryPolicy(max_retries=3, backoff_s=0.001)
+    with pytest.raises(RuntimeError, match=msg.split(":")[0]):
+        rp.run(step)
+    assert len(calls) == 1
 
 
 def test_straggler_monitor():
