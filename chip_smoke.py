@@ -80,12 +80,16 @@ KMEANS_DIST_RTOL = 1e-3
 # reduced width, 6 blocks, 64 tokens). A wrong unpack or codebook gives
 # an error of order 1.
 SERVE_LOGIT_RTOL = 5e-2
-# --chips 4: the L steps on a 4-way data mesh sum the gradient in another
-# order than one chip. Adam's first steps move each weight by about the
-# learning rate whatever its gradient's size, so where an entry is near
-# zero the order can flip its update's sign; after 3 steps at lr 1e-3 the
-# losses of a random-init model differ by parts per thousand.
-LOSS_RTOL = 1e-2
+# --chips 4: both LC jobs compute in float32, so the mesh job differs
+# from the one-device job only in the order of the data-parallel sums:
+# at batch 4 on four CPU devices (reduced width) the LC-job losses agree
+# to 2.7e-5 and one train step's loss and gradient norm to 5e-7, while a
+# planted fault, each shard's batch a copy of the first (the all-reduced
+# gradient is one shard's), moves the gradient norm by 1.5. In bfloat16
+# the rounding of partial sums grows through Adam's steps (batch 8:
+# 6.7e-3 in the losses, 0.14 in the gradient norm), too close to bound.
+LOSS_RTOL = 1e-3
+STEP_RTOL = 1e-3
 N_REQUESTS, PROMPT_MIN, PROMPT_MAX, MAX_NEW = 8, 16, 512, 64
 SLOTS, PREFILL_CHUNK = 4, 8
 
@@ -132,7 +136,7 @@ def device_phase() -> dict:
 # 2. LC job
 # ----------------------------------------------------------------------
 def make_lc(cfg) -> LCAlgorithm:
-    return LCAlgorithm(default_tasks(cfg, "quantize+prune"),
+    return LCAlgorithm(default_tasks(cfg, "prune"),
                        exponential_mu_schedule(MU0, MU_A, LC_STEPS))
 
 
@@ -396,42 +400,18 @@ def serve_phase(cfg_full):
 # ----------------------------------------------------------------------
 # --chips 4: the mesh path
 # ----------------------------------------------------------------------
-def mesh_phase(cfg):
-    devs = jax.devices()
-    check(len(devs) == 4, f"--chips 4 needs 4 devices, found {len(devs)}")
-    batch = BATCHES[0]
-    log("mesh", f"global batch {batch} x {SEQ} on both jobs: the largest "
-        "that the one-chip run fits on one chip (the one-device job fails "
-        "here if it does not)")
-    # Both jobs run every C-step group on the Pallas kernels: they sum
-    # each item tile by tile in grid order, whatever the launch's item
-    # count, so the mesh (3 items per chip) must match one device (10)
-    # bit for bit. XLA's jnp k-means, the "auto" route of the large
-    # groups, may reduce in an order that depends on that count.
-    mesh, one_mesh = make_cstep_mesh(), make_debug_mesh()
-    one = make_lc(cfg).set_backend("pallas").set_mesh(one_mesh)
-    log("mesh", f"LC job on mesh {dict(mesh.shape)}, C steps on pallas")
-    tr4, st4, lcs4, wall4 = run_lc_job(
-        cfg, batch, mesh, "mesh", make_lc(cfg).set_backend("pallas"))
-    losses4 = [r["loss"] for r in tr4.history]
-    c_ms4 = [round(r["c_step_ms"], 1) for r in tr4.history]
-    params = st4["params"]
-    for g in tr4.lc.group_summary(params):
-        log("mesh", f"group {g['tasks'][0]}..: items={g['items']} "
-            f"spec={g['spec']} shard_mode={g['shard_mode']} "
-            f"backend={g['backend']}")
-    del st4
-
-    # one C step from the mesh job's final state, on 4 chips and on 1.
-    # The C step donates its LC state, and moving a replicated array to
-    # device 0 hands over the buffer of its shard there, so move copies
+def compare_csteps(lc4, lc1, params, lc_state, one_mesh):
+    """One C step on the default "auto" route from the same state on the
+    mesh and on one device; returns the Θ and Δ(Θ) leaves that are not bit-identical."""
+    # the C step donates its LC state, and moving a replicated array to
+    # device 0 hands over the buffer of its shard there: pass copies
     to1 = jax.tree_util.tree_map(
         lambda x: jax.device_put(jnp.copy(x), NamedSharding(one_mesh, P())),
-        (params, lcs4))
-    out1 = one.resolve(to1[0]).c_step(*to1)
-    out4 = tr4.lc.c_step(params, lcs4)
+        (params, lc_state))
+    out1 = lc1.resolve(to1[0]).c_step(*to1)
+    out4 = lc4.c_step(params, jax.tree_util.tree_map(jnp.copy, lc_state))
     n_leaves, differ = 0, []
-    for t in tr4.lc.tasks:
+    for t in lc4.tasks:
         for part in ("theta", "a"):
             for a4, a1 in zip(
                     jax.tree_util.tree_leaves(out4["tasks"][t.name][part]),
@@ -439,21 +419,89 @@ def mesh_phase(cfg):
                 n_leaves += 1
                 if not np.array_equal(np.asarray(a4), np.asarray(a1)):
                     differ.append(f"{t.name}/{part}")
-    log("mesh", f"C step (pallas) on 4 chips vs 1: {n_leaves} Θ and Δ(Θ) "
+    log("mesh", f"C step (auto) on 4 chips vs 1: {n_leaves} Θ and Δ(Θ) "
         f"leaves, {len(differ)} differ" + (f": {differ}" if differ else ""))
-    check(not differ, "mesh C step differs from one device")
-    del tr4, params, lcs4, to1, out1, out4
-    gc.collect()                   # free the mesh job's device buffers
+    return differ
 
-    tr1, _, _, wall1 = run_lc_job(cfg, batch, one_mesh, "one", one)
+
+def step_reading(trainer, state, batch) -> dict:
+    """Loss and pre-clip gradient norm of one train step."""
+    _, m = trainer.train_step(state, batch)
+    return {k: float(m[k]) for k in ("loss", "grad_norm")}
+
+
+def rel_err(a: float, b: float) -> float:
+    return abs(a - b) / abs(b)
+
+
+def mesh_phase(cfg):
+    devs = jax.devices()
+    check(len(devs) == 4, f"--chips 4 needs 4 devices, found {len(devs)}")
+    cfg = cfg.with_(dtype="float32")
+    # float32 doubles the activations: the one-device job at batch 8 ran
+    # out of memory on a v5e, though its AOT compile asks 12.0 GiB of
+    # 15.75; at batch 4 it asks 8.4
+    batch = 4
+    log("mesh", f"global batch {batch} x {SEQ} on both jobs, float32 "
+        "compute (see LOSS_RTOL)")
+    mesh, one_mesh = make_cstep_mesh(), make_debug_mesh()
+    # the one-device job first, while device 0 holds nothing else: it
+    # needs most of the chip, the mesh job a quarter of the batch
+    one = make_lc(cfg).set_mesh(one_mesh)
+    tr1, st1, _, wall1 = run_lc_job(cfg, batch, one_mesh, "one", one)
     losses1 = [r["loss"] for r in tr1.history]
-    worst = max(abs(a - b) / abs(b) for a, b in zip(losses4, losses1))
-    log("mesh", f"L-step losses 4 chips {losses4} vs 1 chip {losses1}: "
-        f"max rel {worst:.3g} (bound {LOSS_RTOL})")
-    check(worst <= LOSS_RTOL, "mesh losses off the one-chip job")
+    c_ms1 = [round(r["c_step_ms"], 1) for r in tr1.history]
+    # one train step from the job's final state on the next batch; the
+    # mesh takes the same step from the same state below
+    b = tr1.data.batch_at(LC_STEPS * STEPS_PER_L)
+    step1 = step_reading(tr1, st1, b)
+    host = jax.device_get(st1)
+    del tr1, st1
+    gc.collect()
+    in_use = (devs[0].memory_stats() or {}).get("bytes_in_use")
+    log("mesh", f"device 0 bytes in use after the one-device job: {in_use}")
+
+    log("mesh", f"LC job on mesh {dict(mesh.shape)}, C steps on 'auto'")
+    tr4, st4, lcs4, wall4 = run_lc_job(cfg, batch, mesh, "mesh")
+    losses4 = [r["loss"] for r in tr4.history]
+    c_ms4 = [round(r["c_step_ms"], 1) for r in tr4.history]
+    params = st4["params"]
+    for g in tr4.lc.group_summary(params):
+        log("mesh", f"group {g['tasks'][0]}..: items={g['items']} "
+            f"spec={g['spec']} shard_mode={g['shard_mode']} "
+            f"backend={g['backend']}")
+    # every check below runs after every reading is printed
+    differ = compare_csteps(tr4.lc, one, params, lcs4, one_mesh)
+    shardings = jax.tree_util.tree_map(lambda x: x.sharding, st4)
+    del st4, lcs4, params
+    gc.collect()
+    # the data-parallel step, and the same step with a planted fault:
+    # every shard of the batch a copy of the first, so the all-reduced
+    # gradient is the first shard's alone
+    state = jax.device_put(host, shardings)
+    n = mesh.shape["data"]
+    step4 = step_reading(tr4, state, b)
+    fault = step_reading(tr4, state, {
+        k: jnp.tile(v[:v.shape[0] // n], (n, 1)) for k, v in b.items()})
+    del tr4, state, host
+
+    sound = max(rel_err(x, y) for x, y in zip(losses4, losses1))
+    log("mesh", f"LC-job losses 4 chips {losses4} vs 1 chip {losses1}: "
+        f"max rel {sound:.3g} (bound {LOSS_RTOL})")
+    step_rel = {k: rel_err(step4[k], step1[k]) for k in step1}
+    fault_rel = {k: rel_err(fault[k], step1[k]) for k in step1}
+    log("mesh", f"one train step from the same state: 4 chips {step4}, "
+        f"1 chip {step1}, rel {step_rel} (bound {STEP_RTOL}); planted "
+        f"one-shard gradient {fault}, rel {fault_rel}")
     log("mesh", "smoke reading, one run, not a benchmark: job wall 4 chips "
         f"{wall4:.1f} s, 1 chip {wall1:.1f} s; c_step_ms 4 chips {c_ms4}, "
-        f"1 chip {[round(r['c_step_ms'], 1) for r in tr1.history]}")
+        f"1 chip {c_ms1}")
+    check(not differ, f"mesh C step differs from one device: {differ}")
+    check(sound <= LOSS_RTOL, "mesh LC-job losses off the one-chip job")
+    check(max(step_rel.values()) <= STEP_RTOL,
+          "mesh train step off the one-chip step")
+    check(fault_rel["grad_norm"] > STEP_RTOL,
+          "the step bound does not catch a gradient from one shard only")
 
 
 def main() -> None:

@@ -622,10 +622,13 @@ def grouped_compress(tasks: Sequence[CompressionTask], xs: dict,
             def solve(x, th, mu_, t=t):
                 return solve_task(t, x, th, mu_, backend=backend)
 
-            if mesh is not None and mesh.devices.size > 1:
+            if mesh is not None and mesh.devices.size > 1 and \
+                    _task_solver(t.scheme, backend)[1] in \
+                    ("pallas", "interpret"):
                 # GSPMD cannot partition a Pallas (Mosaic) call, even on
                 # replicated operands: every device solves the whole task
-                # inside a manual region instead
+                # inside a manual region instead. Other solvers stay
+                # with GSPMD, which may split them.
                 solve = shard_map(solve, mesh, in_specs=(P(), P(), P()),
                                   out_specs=P())
             theta = solve(xs[t.name], thetas[t.name], mu)

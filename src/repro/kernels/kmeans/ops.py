@@ -110,8 +110,13 @@ def kmeans_batched(w: jnp.ndarray, codebooks0: jnp.ndarray,
     stack. With ``kvalid=None`` (or all-K_max) the masking is the
     identity and the solve is unchanged (bit-identical on ``"jnp"``).
 
-    ``impl``: ``"jnp"`` vmaps the core compare-count solver
-    (bit-identical to the per-task scheme path); ``"interpret"`` /
+    ``impl``: ``"jnp"`` maps the core compare-count solver over the
+    items one at a time (bit-identical to the per-task scheme path). A
+    vmapped solve would let a TPU tile each reduction by the launch's
+    item count (a (3, P) stack gets (4, 128) tiles, a (10, P) stack
+    (8, 128)), which changes the order of the cluster sums; mapped, every
+    item runs the same compiled program, so a mesh shard's few items
+    give the bits of the whole group on one device. ``"interpret"`` /
     ``"pallas"`` run the batched items-grid kernel — one pallas_call per
     Lloyd step for the whole group, per-item codebooks VMEM-resident.
     The kernel's moment accumulation order differs from the jnp masked
@@ -128,8 +133,8 @@ def kmeans_batched(w: jnp.ndarray, codebooks0: jnp.ndarray,
         # deferred import: kernels must stay importable without core
         # (core.grouping imports the dispatch layer at module load)
         from repro.core.schemes.quantize import kmeans_1d
-        return jax.vmap(lambda wi, ci: kmeans_1d(wi, ci, iters))(
-            w, codebooks0)
+        return jax.lax.map(lambda wc: kmeans_1d(*wc, iters),
+                           (w, codebooks0))
     interpret = impl != "pallas"
     w = w.astype(jnp.float32)
     cb = jnp.sort(codebooks0.astype(jnp.float32), axis=-1)

@@ -28,17 +28,10 @@ from repro.launch.mesh import make_debug_mesh
 from repro.models.transformer import init_params
 from repro.runtime import FaultInjector, LCTrainer, TrainerConfig
 
-#: stacked projections the top-κ task prunes (attention and gated FFN)
-PRUNED = ("w_gate", "w_up", "w_down", "wq", "wk", "wv", "wo")
-#: the remaining stacked projections (mixer in/out and xLSTM projections)
-PROJECTIONS = ("in_proj", "out_proj", "up_proj", "down_proj", "w")
-COMPRESSIONS = ("quantize", "prune", "quantize+prune")
-#: fraction of the pruned stacks' weights the top-κ task keeps
-PRUNE_KEEP = 0.1
-
-
-def _stacks(names) -> str:
-    return rf"stages/.*/({'|'.join(names)})$"
+#: stacked attention and gated-FFN projections, and the other stacked
+#: projections (mixer in/out and xLSTM)
+_ATTN_FFN = "w_gate|w_up|w_down|wq|wk|wv|wo"
+_OTHER = "in_proj|out_proj|up_proj|down_proj|w"
 
 
 def default_tasks(cfg, compression: str = "quantize"):
@@ -46,31 +39,27 @@ def default_tasks(cfg, compression: str = "quantize"):
 
     * ``"quantize"``: per-layer adaptive 4-bit codebooks on every
       stacked projection (AsStacked ⇒ one item per layer).
-    * ``"prune"``: one ℓ0 budget over the attention and gated-FFN
-      stacks, keeping the :data:`PRUNE_KEEP` fraction of largest
-      weights.
-    * ``"quantize+prune"``: the prune task, plus 4-bit codebooks on the
-      other stacked projections (tasks must not share parameters).
+    * ``"prune"``: one ℓ0 budget keeping the largest tenth of the
+      attention and gated-FFN stacks' weights, plus 4-bit codebooks on
+      the other stacked projections (tasks must not share parameters).
     """
-    if compression not in COMPRESSIONS:
-        raise ValueError(compression)
-    tasks = []
-    if "prune" in compression:
+    def quantize(names):
+        return CompressionTask(
+            "quantize-stacks", rf"stages/.*/({names})$",
+            AsStacked("vector"), AdaptiveQuantization(k=16, iters=10))
+
+    if compression == "quantize":
+        return [quantize(f"{_ATTN_FFN}|{_OTHER}")]
+    if compression == "prune":
+        pattern = rf"stages/.*/({_ATTN_FFN})$"
         shapes = jax.eval_shape(lambda k: init_params(k, cfg),
                                 jax.random.PRNGKey(0))
-        rx = re.compile(_stacks(PRUNED))
         total = sum(leaf.size for p, leaf in flatten_params(shapes).items()
-                    if rx.search(p))
-        tasks.append(CompressionTask(
-            "prune-stacks", _stacks(PRUNED), AsVector(),
-            ConstraintL0Pruning(kappa=max(1, int(PRUNE_KEEP * total)))))
-    if "quantize" in compression:
-        names = PROJECTIONS if "prune" in compression \
-            else PRUNED + PROJECTIONS
-        tasks.append(CompressionTask(
-            "quantize-stacks", _stacks(names),
-            AsStacked("vector"), AdaptiveQuantization(k=16, iters=10)))
-    return tasks
+                    if re.search(pattern, p))
+        return [CompressionTask("prune-stacks", pattern, AsVector(),
+                                ConstraintL0Pruning(kappa=max(1, total // 10))),
+                quantize(_OTHER)]
+    raise ValueError(compression)
 
 
 def main():

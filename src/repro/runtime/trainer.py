@@ -44,7 +44,7 @@ from repro.core.algorithm import LCAlgorithm
 from repro.core.state import ready_probe
 from repro.core.tasks import get_path
 from repro.data.pipeline import Prefetcher
-from repro.distributed.sharding import use_mesh
+from repro.distributed.sharding import resolve_spec, use_mesh
 from repro.launch.steps import make_train_step, stable_lc_refs
 from repro.optim import AdamW
 from repro.runtime.fault_tolerance import (
@@ -124,9 +124,17 @@ class LCTrainer:
         self.ckpt = (CheckpointManager(self.tcfg.ckpt_dir,
                                        self.tcfg.keep_last)
                      if self.tcfg.ckpt_dir else None)
-        self._train_step = jax.jit(make_train_step(
-            cfg, self.optimizer, lr=self.tcfg.lr,
-            clip_norm=self.tcfg.clip_norm, with_lc=True))
+        # under a mesh the weights and optimizer state come back
+        # replicated, as init_state committed them: left to GSPMD, some
+        # optimizer moments return in a layout of their own and the next
+        # call compiles the step again. The LC refs pass through as they
+        # came.
+        rep = None if mesh is None else NamedSharding(mesh, P())
+        self._train_step = jax.jit(
+            make_train_step(cfg, self.optimizer, lr=self.tcfg.lr,
+                            clip_norm=self.tcfg.clip_norm, with_lc=True),
+            out_shardings=({"params": rep, "opt": rep, "step": rep,
+                            "lc": None}, None))
         self.history: list[dict] = []
         # in-flight LC boundary of the overlapped pipeline (None when
         # nothing is in flight / overlap is off)
@@ -167,7 +175,25 @@ class LCTrainer:
         else:
             batch = self.data.batch_at(step) \
                 if hasattr(self.data, "batch_at") else self.data(step)
-        return self._train_step(state, batch)
+        return self.train_step(state, batch)
+
+    def train_step(self, state, batch):
+        """One optimizer step on ``batch``: ``(state, metrics)``. Under a
+        mesh it is data-parallel: the batch splits over the mesh's batch
+        axes (:meth:`shard_batch`), and the model's activation
+        constraints, traced under the mesh, keep it split."""
+        with use_mesh(self.mesh):
+            return self._train_step(state, self.shard_batch(batch))
+
+    def shard_batch(self, batch):
+        """``batch`` split over the mesh's batch axes; as it is without
+        a mesh."""
+        if self.mesh is None:
+            return batch
+        return jax.tree_util.tree_map(
+            lambda x: jax.device_put(x, NamedSharding(self.mesh, resolve_spec(
+                ("batch",) + (None,) * (x.ndim - 1), x.shape, self.mesh))),
+            batch)
 
     def _restore_state(self, state):
         """Hard-failure restore with consistent LC bookkeeping.

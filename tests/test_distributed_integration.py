@@ -96,12 +96,12 @@ print("ok", float(metrics["loss"]))
 
 @pytest.mark.parametrize("devices", [1, 4])
 def test_mesh_lc_trainer_compiles_each_step_once(devices):
-    """Two LC steps of ``LCTrainer`` on a data mesh: the C step, the
-    multiplier step and the distortion monitor each compile once. A
-    multiplier λ left in GSPMD's layout, or a new μ placed apart from
-    the state's, made every LC step compile them again. On one device
-    the train step compiles once too; on four, GSPMD returns some
-    optimizer moments in a layout of its own, which costs one more."""
+    """Two LC steps of ``LCTrainer`` on a data mesh: the train step, the
+    C step, the multiplier step and the distortion monitor each compile
+    once. A multiplier λ left in GSPMD's layout, or a new μ placed apart
+    from the state's, made every LC step compile them again; optimizer
+    moments returned in GSPMD's own layout made the train step compile
+    twice on four devices."""
     script = """
 import json
 import jax
@@ -112,7 +112,7 @@ from repro.launch.mesh import make_cstep_mesh
 from repro.launch.train import default_tasks
 from repro.runtime import LCTrainer, TrainerConfig
 cfg = reduced_config(get_config("xlstm-125m"))
-lc = LCAlgorithm(default_tasks(cfg, "quantize+prune"),
+lc = LCAlgorithm(default_tasks(cfg, "prune"),
                  exponential_mu_schedule(1e-4, 1.2, 2))
 tr = LCTrainer(cfg, lc, TokenStream(cfg.vocab_size, 4, 16),
                mesh=make_cstep_mesh(), tcfg=TrainerConfig(steps_per_l=2))
@@ -124,11 +124,46 @@ print(json.dumps({n: f._cache_size() for n, f in steps.items()}))
 """
     out = _run(script, devices=devices)
     compiles = json.loads(out.strip().splitlines()[-1])
-    once = {"c_step", "mult_step", "shifted_distortion"}
-    if devices == 1:
-        once.add("train_step")
+    once = {"train_step", "c_step", "mult_step", "shifted_distortion"}
     assert {n: compiles[n] for n in once} == dict.fromkeys(once, 1), \
         compiles
+
+
+def test_mesh_lc_trainer_step_is_data_parallel():
+    """``LCTrainer``'s train step on a 4-way data mesh splits the batch:
+    each device runs about a quarter of the one-device step's FLOPs, and
+    in float32 the step's loss and gradient norm match one device's. The
+    batch once went in unsharded, and every device ran the whole step."""
+    script = """
+import json
+import jax
+from repro.configs import get_config, reduced_config
+from repro.core import LCAlgorithm, exponential_mu_schedule
+from repro.data import TokenStream
+from repro.distributed.sharding import use_mesh
+from repro.launch.mesh import make_cstep_mesh
+from repro.launch.train import default_tasks
+from repro.runtime import LCTrainer
+cfg = reduced_config(get_config("xlstm-125m")).with_(dtype="float32")
+out = {}
+for name, mesh in (("mesh", make_cstep_mesh()), ("one", None)):
+    lc = LCAlgorithm(default_tasks(cfg), exponential_mu_schedule(1e-4, 1.2, 1))
+    tr = LCTrainer(cfg, lc, TokenStream(cfg.vocab_size, 8, 16), mesh=mesh)
+    state = tr.init_state(jax.random.PRNGKey(0))
+    batch = tr.data.batch_at(0)
+    _, m = tr.train_step(state, batch)
+    with use_mesh(mesh):
+        c = tr._train_step.lower(state, tr.shard_batch(batch)).compile()
+    out[name] = {"loss": float(m["loss"]),
+                 "grad_norm": float(m["grad_norm"]),
+                 "flops": c.cost_analysis()["flops"]}
+print(json.dumps(out))
+"""
+    out = json.loads(_run(script, devices=4).strip().splitlines()[-1])
+    mesh, one = out["mesh"], out["one"]
+    assert mesh["flops"] < 0.4 * one["flops"], out
+    for k in ("loss", "grad_norm"):
+        assert abs(mesh[k] - one[k]) <= 1e-5 * abs(one[k]), out
 
 
 def test_dryrun_cell_subprocess():
