@@ -1,0 +1,95 @@
+#!/usr/bin/env python3
+"""On-chip benchmark of the LC compression job.
+
+    python3 perfbench/run.py --workload <name> --seed <n> --seconds <s> \
+        --trace <0|1>
+
+Runs one cell of ``BENCHMARK.json`` (at the root of the checkout) on the
+accelerator this process finds, and prints one JSON object as the last
+line of standard output:
+
+    {"correct": ..., "attempted": ..., "failed": ..., "metrics": {...},
+     "device": {...}, ["breakdown": {...},] "checks": {...}}
+
+With ``--trace 0`` the metrics are the cell's end-to-end metrics, with
+``--trace 1`` its per-layer metrics (read from a profiler trace of part
+of the window). ``checks`` holds each number that decides ``correct``
+beside its limit; the same lines end standard error.
+
+Everything a cell needs is found by name: the configuration in
+``configs/<config>.json`` (its plain reference beside it), the traffic in
+``traffic/<traffic>.json``, the limits of its comparison in
+``limits/<workload>.json`` and each per-layer metric's reader in
+``metrics/<metric>.py``. A cell of a new model or traffic mix of an
+existing kind needs only new files there and an entry in
+``BENCHMARK.json``.
+
+Exits non-zero and prints no result when JAX finds no TPU, or fewer chips
+than the cell asks for.
+"""
+from __future__ import annotations
+
+import time
+
+T_START = time.perf_counter()
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import sys  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+HERE = Path(__file__).resolve().parent
+ROOT = HERE.parent
+# the persistent compilation cache lives at one fixed path inside the
+# checkout, so only a checkout's first run of a cell compiles
+CACHE_DIR = ROOT / ".jax_cache"
+os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
+sys.path.insert(0, str(HERE))
+sys.path.insert(0, str(ROOT / "src"))
+
+
+def load_cell(name: str) -> dict:
+    """The cell's entry of BENCHMARK.json with its files loaded."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if name not in cells:
+        raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
+    cell = dict(cells[name])
+    cell["config_file"] = json.loads(
+        (HERE / "configs" / f"{cell['config']}.json").read_text())
+    cell["traffic_file"] = json.loads(
+        (HERE / "traffic" / f"{cell['traffic']}.json").read_text())
+    cell["limits"] = json.loads(
+        (HERE / "limits" / f"{name}.json").read_text())
+    cell["end_to_end"] = [m for m in bench["end_to_end"]
+                          if name in m.get("workloads", [name])]
+    cell["per_layer"] = [m for m in bench["per_layer"]
+                         if name in m.get("workloads", [name])]
+    return cell
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+    cell = load_cell(args.workload)
+
+    import harness
+    device = harness.accelerator(cell["chips"])
+    if device is None:
+        return 3
+    harness.enable_cache(CACHE_DIR)
+    job = harness.job(cell)
+    result = job.run(cell, seed=args.seed, seconds=args.seconds,
+                     trace=bool(args.trace), t_start=T_START,
+                     device=device)
+    harness.emit(result)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
