@@ -7,8 +7,9 @@ Designed for 1000+-node behavior, exercised here via fault injection:
   ``max_retries`` the trainer falls back to restore-from-checkpoint.
   A compile error or an out-of-memory error is not transient
   (:func:`is_transient`): it surfaces at once.
-* ``StragglerMonitor`` — per-step wall times vs a rolling median; a step
-  slower than ``factor``× median marks a straggler. The trainer's
+* ``StragglerMonitor`` — per-step times vs a rolling median; a step
+  slower than ``factor``× median marks a straggler. The trainer feeds it
+  each step's host time (its ``lc.step`` span). The trainer's
   response is pluggable (log / re-shard via elastic reload / evict).
 * ``FaultInjector`` — deterministic fault schedule for tests ("fail step
   17 twice, then succeed"), so recovery paths are unit-testable.
@@ -59,6 +60,17 @@ class RetryPolicy:
 
 @dataclass
 class StragglerMonitor:
+    """Marks steps slower than ``factor`` × the median of the last
+    ``window``.
+
+    The trainer observes the host's time per step, its ``lc.step`` span:
+    fault check, batch, shard and dispatch. That is not the device's time
+    per step. While the host runs ahead, dispatch waits behind queued
+    steps and the span follows the device's pace; while the host paces
+    the loop it is the host's own time, and the device idles for the
+    rest. The trainer never blocks on a step for the monitor's sake: a
+    wait per step would serialise dispatch.
+    """
     factor: float = 3.0
     window: int = 32
     times: deque | None = None

@@ -12,6 +12,25 @@ Composes the paper's LC algorithm with the distributed substrate:
     failures, restore-from-checkpoint on hard failure, straggler
     tracking, deterministic seekable data (exact resume).
 
+Host spans and counters (``runtime/spans.py``) time the loop's layers:
+each history record carries ``host_ms`` and ``counts`` per name, and a
+profile shows the same spans on its host track:
+
+    lc.iteration          one LC iteration, set_mu to the record
+      lc.l_step           the L step's steps_per_l optimizer steps
+        lc.step           one step's host work: fault check, batch,
+                          shard, dispatch (a StepTraceAnnotation)
+          lc.step.data    the batch fetch
+      lc.drain            until the device has run what the L step queued
+      lc.c_step           the C step
+    lc.step.starved       counter: steps dispatched after the device had
+                          finished the step before
+
+In the overlapped mode (below) ``lc.drain`` is the wait, if any, for the
+previous boundary's C step, and ``lc.c_step`` the C step's dispatch. Its
+records are emitted later, by ``_apply_pending``, each with the spans of
+its own iteration, taken when the iteration's boundary was dispatched.
+
 Two execution modes (``TrainerConfig.overlap``):
 
 * ``"off"`` — the strictly serial loop above: every C step drains the
@@ -49,6 +68,7 @@ from repro.launch.steps import make_train_step, stable_lc_refs
 from repro.optim import AdamW
 from repro.runtime.fault_tolerance import (
     FaultInjector, RetryPolicy, StragglerMonitor, is_transient)
+from repro.runtime.spans import Spans
 
 log = logging.getLogger("repro.trainer")
 
@@ -136,6 +156,7 @@ class LCTrainer:
             out_shardings=({"params": rep, "opt": rep, "step": rep,
                             "lc": None}, None))
         self.history: list[dict] = []
+        self.spans = Spans()
         # in-flight LC boundary of the overlapped pipeline (None when
         # nothing is in flight / overlap is off)
         self._pending: dict | None = None
@@ -169,13 +190,19 @@ class LCTrainer:
 
     # ------------------------------------------------------------------
     def _one_step(self, state, step: int):
-        self.faults.maybe_fail(step)
-        if self._prefetcher is not None:
-            batch = self._prefetcher.batch_at(step)
-        else:
-            batch = self.data.batch_at(step) \
-                if hasattr(self.data, "batch_at") else self.data(step)
-        return self.train_step(state, batch)
+        with self.spans.span("lc.step", step=step):
+            self.faults.maybe_fail(step)
+            with self.spans.span("lc.step.data"):
+                if self._prefetcher is not None:
+                    batch = self._prefetcher.batch_at(step)
+                else:
+                    batch = self.data.batch_at(step) \
+                        if hasattr(self.data, "batch_at") else self.data(step)
+            # a poll, not a wait: the device had already finished the
+            # step before this one, so it idles until this dispatch
+            self.spans.count("lc.step.starved",
+                             int(state["step"].is_ready()))
+            return self.train_step(state, batch)
 
     def train_step(self, state, batch):
         """One optimizer step on ``batch``: ``(state, metrics)``. Under a
@@ -248,7 +275,6 @@ class LCTrainer:
         done = 0
         restores = 0  # consecutive, reset by any completed step
         while step < end_step:
-            t0 = time.time()
             try:
                 state, metrics = self.retry.run(
                     self._one_step, state, step,
@@ -271,9 +297,10 @@ class LCTrainer:
                     continue
                 raise
             restores = 0
-            dt = time.time() - t0
-            if self.straggler.observe(dt):
-                log.warning("straggler: step %d took %.3fs", step, dt)
+            host_ms = self.spans.last_ms["lc.step"]
+            if self.straggler.observe(host_ms):
+                log.warning("straggler: step %d took %.1f ms of host time "
+                            "(lc.step)", step, host_ms)
             if self.ckpt and step > 0 \
                     and step % self.tcfg.ckpt_every == 0:
                 self.ckpt.save(state, step)
@@ -306,48 +333,52 @@ class LCTrainer:
         every LC boundary. Step-for-step identical to the pre-overlap
         trainer (enforced by tests/test_trainer_overlap.py)."""
         lc_state = self._lc_state
+        self.spans.take()  # a record holds its own iteration's spans only
         for k, mu in enumerate(schedule):
-            lc_state = self.lc.set_mu(lc_state, mu, k)
-            self._lc_state = lc_state
-            state["lc"] = self._refs_from_lc(state["params"], lc_state)
-            pen0 = float(self.lc.penalty(state["params"], lc_state))
+            with self.spans.span("lc.iteration"):
+                lc_state = self.lc.set_mu(lc_state, mu, k)
+                self._lc_state = lc_state
+                state["lc"] = self._refs_from_lc(state["params"], lc_state)
+                pen0 = float(self.lc.penalty(state["params"], lc_state))
 
-            state, metrics, global_step = self._l_step(
-                state, k, global_step)
+                with self.spans.span("lc.l_step"):
+                    state, metrics, global_step = self._l_step(
+                        state, k, global_step)
 
-            params = state["params"]
-            if self.tcfg.monitor_distortion:
-                d_pre = self.lc.shifted_distortion(params, lc_state)
-                jax.block_until_ready(d_pre)
-            # drain in-flight L-step work so c_step_ms times the C step
-            # alone, not the async dispatch chain behind it
-            jax.block_until_ready(params)
-            t0 = time.time()
-            lc_state = self.lc.c_step(params, lc_state)
-            jax.block_until_ready(lc_state)
-            c_step_ms = (time.time() - t0) * 1e3
-            c_violations = []
-            if self.tcfg.monitor_distortion:
-                d_post = self.lc.shifted_distortion(params, lc_state)
-                c_violations = self._check_violations(d_pre, d_post)
-            lc_state = self.lc.multiplier_step(params, lc_state)
-            self._lc_state = lc_state
-            state["lc"] = self._refs_from_lc(params, lc_state)
+                params = state["params"]
+                # drain in-flight L-step work so that lc.c_step times the
+                # C step alone, not the async dispatch chain behind it
+                with self.spans.span("lc.drain"):
+                    if self.tcfg.monitor_distortion:
+                        d_pre = self.lc.shifted_distortion(params, lc_state)
+                        jax.block_until_ready(d_pre)
+                    jax.block_until_ready(params)
+                with self.spans.span("lc.c_step"):
+                    lc_state = self.lc.c_step(params, lc_state)
+                    jax.block_until_ready(lc_state)
+                c_violations = []
+                if self.tcfg.monitor_distortion:
+                    d_post = self.lc.shifted_distortion(params, lc_state)
+                    c_violations = self._check_violations(d_pre, d_post)
+                lc_state = self.lc.multiplier_step(params, lc_state)
+                self._lc_state = lc_state
+                state["lc"] = self._refs_from_lc(params, lc_state)
 
-            dist = {n: float(v) for n, v in
-                    self.lc.distortion(params, lc_state).items()}
-            rec = {
-                "lc_step": k, "mu": float(mu),
-                "loss": float(metrics.get("loss", np.nan)),
-                "ce": float(metrics.get("ce", np.nan)),
-                "penalty_start": pen0,
-                "distortion": dist,
-                "c_step_ms": c_step_ms,
-                "c_step_violations": c_violations,
-                "compression_ratio": float(
-                    self.lc.compression_ratio(params, lc_state)),
-                "stragglers": self.straggler.stragglers,
-            }
+                dist = {n: float(v) for n, v in
+                        self.lc.distortion(params, lc_state).items()}
+                rec = {
+                    "lc_step": k, "mu": float(mu),
+                    "loss": float(metrics.get("loss", np.nan)),
+                    "ce": float(metrics.get("ce", np.nan)),
+                    "penalty_start": pen0,
+                    "distortion": dist,
+                    "c_step_ms": self.spans.last_ms["lc.c_step"],
+                    "c_step_violations": c_violations,
+                    "compression_ratio": float(
+                        self.lc.compression_ratio(params, lc_state)),
+                    "stragglers": self.straggler.stragglers,
+                }
+            rec.update(self.spans.take())
             self.history.append(rec)
             log.info("LC step %d: %s", k, rec)
 
@@ -382,6 +413,7 @@ class LCTrainer:
         """
         lc_state = self._lc_state
         self._pending = None  # a prior aborted run must not leak in
+        self.spans.take()
         swap_after = self.tcfg.swap_after
 
         def on_microbatch(st, done):
@@ -394,57 +426,64 @@ class LCTrainer:
             return st
 
         for k, mu in enumerate(schedule):
-            lc_state = self.lc.set_mu(lc_state, mu, k)
-            self._lc_state = lc_state
-            if self._pending is None:
-                # cold boundary (first LC step): fresh refs, as serial
-                state["lc"] = self._refs_from_lc(state["params"], lc_state)
-            else:
-                # stale-refs window: keep the previous Δ(Θ)/λ in the
-                # penalty while the C step runs; only μ advances now
-                state["lc"] = dict(state["lc"], mu=jnp.float32(mu))
-            pen0 = self.lc.penalty(state["params"], lc_state)  # async
+            with self.spans.span("lc.iteration"):
+                lc_state = self.lc.set_mu(lc_state, mu, k)
+                self._lc_state = lc_state
+                if self._pending is None:
+                    # cold boundary (first LC step): fresh refs, as serial
+                    state["lc"] = self._refs_from_lc(state["params"], lc_state)
+                else:
+                    # stale-refs window: keep the previous Δ(Θ)/λ in the
+                    # penalty while the C step runs; only μ advances now
+                    state["lc"] = dict(state["lc"], mu=jnp.float32(mu))
+                pen0 = self.lc.penalty(state["params"], lc_state)  # async
 
-            state, metrics, global_step = self._l_step(
-                state, k, global_step, on_microbatch=on_microbatch)
+                with self.spans.span("lc.l_step"):
+                    state, metrics, global_step = self._l_step(
+                        state, k, global_step, on_microbatch=on_microbatch)
 
-            # boundary k consumes post-multiplier λ from boundary k-1:
-            # if the swap hasn't happened yet (slow C step or large
-            # swap_after), force it now
-            if self._pending is not None:
-                state = self._apply_pending(
-                    state, block=True, done=self.tcfg.steps_per_l)
+                # boundary k consumes post-multiplier λ from boundary k-1:
+                # if the swap hasn't happened yet (slow C step or large
+                # swap_after), force it now
+                with self.spans.span("lc.drain"):
+                    if self._pending is not None:
+                        state = self._apply_pending(
+                            state, block=True, done=self.tcfg.steps_per_l)
 
-            # ---- LC boundary k: dispatch everything, block on nothing
-            params = state["params"]
-            d_pre = (self.lc.shifted_distortion(params, lc_state)
-                     if self.tcfg.monitor_distortion else None)
-            t_dispatch = time.time()
-            lc_after_c = self.lc.c_step_async(params, lc_state)
-            d_post = (self.lc.shifted_distortion(params, lc_after_c)
-                      if self.tcfg.monitor_distortion else None)
-            lc_state = self.lc.multiplier_step_async(params, lc_after_c)
-            # compression_ratio only reads parameter *shapes* from w —
-            # keep shape structs, not the arrays, so the boundary
-            # snapshot doesn't pin a second full parameter generation
-            # on device for the length of the stale window
-            param_shapes = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
-            self._pending = {
-                "k": k, "mu": float(mu), "metrics": metrics,
-                "pen0": pen0, "params": param_shapes, "lc_state": lc_state,
-                "d_pre": d_pre, "d_post": d_post,
-                "dist": self.lc.distortion(params, lc_state),
-                "t_dispatch": t_dispatch, "t_ready": None,
-                "probe": ready_probe(lc_state),
-            }
-            # the C step also overlaps *data loading*: start building
-            # the next L step's first microbatch while the boundary
-            # chain is in flight (global_step is exactly the step index
-            # the next _l_step consumes first). The final boundary has
-            # no next L step — don't strand a batch nobody consumes.
-            if self._prefetcher is not None and k + 1 < len(schedule):
-                self._prefetcher.prefetch(global_step)
+                # ---- LC boundary k: dispatch everything, block on nothing
+                params = state["params"]
+                d_pre = (self.lc.shifted_distortion(params, lc_state)
+                         if self.tcfg.monitor_distortion else None)
+                t_dispatch = time.perf_counter()
+                with self.spans.span("lc.c_step"):
+                    lc_after_c = self.lc.c_step_async(params, lc_state)
+                d_post = (self.lc.shifted_distortion(params, lc_after_c)
+                          if self.tcfg.monitor_distortion else None)
+                lc_state = self.lc.multiplier_step_async(params, lc_after_c)
+                # compression_ratio only reads parameter *shapes* from w —
+                # keep shape structs, not the arrays, so the boundary
+                # snapshot doesn't pin a second full parameter generation
+                # on device for the length of the stale window
+                param_shapes = jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
+                self._pending = {
+                    "k": k, "mu": float(mu), "metrics": metrics,
+                    "pen0": pen0, "params": param_shapes, "lc_state": lc_state,
+                    "d_pre": d_pre, "d_post": d_post,
+                    "dist": self.lc.distortion(params, lc_state),
+                    "t_dispatch": t_dispatch, "t_ready": None,
+                    "probe": ready_probe(lc_state),
+                }
+                # the C step also overlaps *data loading*: start building
+                # the next L step's first microbatch while the boundary
+                # chain is in flight (global_step is exactly the step index
+                # the next _l_step consumes first). The final boundary has
+                # no next L step — don't strand a batch nobody consumes.
+                if self._prefetcher is not None and k + 1 < len(schedule):
+                    self._prefetcher.prefetch(global_step)
+            # the record, emitted later by _apply_pending, holds the spans
+            # of this iteration alone
+            self._pending["spans"] = self.spans.take()
 
         # drain the final boundary (no L step left to overlap with);
         # an empty μ schedule never dispatched one
@@ -464,7 +503,7 @@ class LCTrainer:
         if block:
             jax.block_until_ready(p["probe"])
         if p["t_ready"] is None:
-            p["t_ready"] = time.time()
+            p["t_ready"] = time.perf_counter()
         refs = self._refs_from_lc(state["params"], p["lc_state"])
         state["lc"] = stable_lc_refs(refs, state["lc"])
         self._pending = None
@@ -485,6 +524,7 @@ class LCTrainer:
                 self.lc.compression_ratio(p["params"], p["lc_state"])),
             "stragglers": self.straggler.stragglers,
             "swap_after_microbatches": done,
+            **p["spans"],
         }
         self.history.append(rec)
         log.info("LC step %d: %s", p["k"], rec)
