@@ -65,6 +65,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.schemes.base import (
     add_leading_axis, drop_leading_axis, pack_thetas, pack_thetas_padded,
     slice_theta_like, unpack_thetas)
+from repro.core.schemes.quantize import AdaptiveQuantization, lookup_kind
 from repro.core.tasks import CompressionTask
 from repro.distributed.sharding import (
     items_partition, shard_map, stacked_sharding)
@@ -218,6 +219,11 @@ def describe_groups(tasks: Sequence[CompressionTask], xs: dict,
     implementation that will run — e.g. a ``"pallas"`` request off-TPU
     reports ``"interpret"``.
 
+    ``decompress`` reports how a k-means group reads Δ(Θ) =
+    codebook[assign] at its largest K (mixed-K groups pad codebooks to
+    it): ``"select"`` or ``"gather"`` (``quantize.lookup_kind``); ``None``
+    for every other scheme.
+
     ``planner="on"`` additionally attaches each multi-task group's
     :class:`repro.analysis.cost.GroupPlan` as a ``plan`` dict (modeled
     roofline terms, chosen backend/tile/chunks/shard_mode, recorded
@@ -266,6 +272,9 @@ def describe_groups(tasks: Sequence[CompressionTask], xs: dict,
             "solver": t0.scheme.solver if solver_fn is not None else None,
             "backend": actual,
             "plan": plan_dict,
+            "decompress": (lookup_kind(max(t.scheme.k for t in group))
+                           if isinstance(t0.scheme, AdaptiveQuantization)
+                           else None),
         })
     return out
 

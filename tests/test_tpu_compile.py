@@ -20,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core import AsStacked, AsVector, CompressionTask, LCAlgorithm
 from repro.core.schemes import AdaptiveQuantization, ConstraintL0Pruning
+from repro.core.schemes.quantize import QuantTheta
 from repro.kernels import dispatch
 from repro.kernels.kmeans.kmeans import kmeans_assign_moments_batched
 from repro.kernels.prune.prune import count_above_batched, mask_apply_batched
@@ -30,6 +31,7 @@ ITEMS = 10                         # one packed k-means group: 5 tasks x 2
 ITEM = D_MODEL * D_UP              # one stacked layer's projection, flat
 K = 16                             # 4-bit codebook
 SLOTS = 8                          # decode rows, padded to one sublane tile
+MLP_GROUP = (3, 3072 * 8192)       # phi3-mini's w_gate/w_up/w_down group
 
 
 @pytest.fixture(scope="module")
@@ -115,3 +117,19 @@ def test_singleton_kernel_cstep_compiles_on_mesh(mesh4, monkeypatch):
                                                  sharding=rep))
     text = lc._c_step.lower(put(params), put(state)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_kmeans_decompress_compiles_without_gather(one_chip):
+    """The grouped C step's vmapped Δ(Θ) = codebook[assign] at phi3-mini's
+    MLP group: a gather there reads 1.43 s by ``cost_analysis``; the
+    select tree is one elementwise fusion bound by its 0.8 GB."""
+    theta = QuantTheta(
+        jax.ShapeDtypeStruct((MLP_GROUP[0], K), jnp.float32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct(MLP_GROUP, jnp.int32, sharding=one_chip))
+    compiled = jax.jit(jax.vmap(AdaptiveQuantization(k=K).decompress)) \
+        .lower(theta).compile()
+    assert " gather(" not in compiled.as_text()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["optimal_seconds"] < 0.02
