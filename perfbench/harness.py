@@ -2,10 +2,13 @@
 configuration, seeds, spans, the profiler, per-layer readers, the result."""
 from __future__ import annotations
 
+import dataclasses
 import json
 import shutil
 import sys
 import tempfile
+import types
+import typing
 from pathlib import Path
 
 import jax
@@ -74,19 +77,62 @@ class CompileCounter:
 # ----------------------------------------------------------------------
 # configuration and seeds
 # ----------------------------------------------------------------------
+# keys of a configuration file that describe it and are not fields of
+# ``ModelConfig``: the model's reference reads ``sliding_window``
+DESCRIPTIVE = ("source", "deployment", "reference", "published", "reduced",
+               "why_reduced", "assumed", "sliding_window")
+# mixers whose cost grows with the square of the context
+QUADRATIC = ("attn", "mla")
+
+
+def _field_types(cls) -> dict:
+    """``{field name: type}`` of a dataclass, its annotations resolved."""
+    hints = typing.get_type_hints(cls)
+    return {f.name: hints[f.name] for f in dataclasses.fields(cls)}
+
+
+def _build(tp, v):
+    """The JSON value ``v`` as a value of type ``tp``: a nested config
+    dataclass from its dict (or from a list of its fields in order, as a
+    ``LayerSpec`` from ``[mixer, ffn, window]``), a tuple from a list;
+    ``None`` stays ``None``."""
+    if v is None:
+        return None
+    origin, args = typing.get_origin(tp), typing.get_args(tp)
+    if origin in (typing.Union, types.UnionType):
+        (tp,) = [a for a in args if a is not type(None)]
+        return _build(tp, v)
+    if origin is tuple:
+        return tuple(_build(args[0], x) for x in v)
+    if dataclasses.is_dataclass(tp):
+        if isinstance(v, list):
+            return tp(*v)
+        hints = _field_types(tp)
+        return tp(**{k: _build(hints.get(k), x) for k, x in v.items()})
+    return v
+
+
 def model_config(c: dict):
-    """The program's ``ModelConfig`` built from a configuration file."""
-    from repro.configs.base import LayerSpec, ModelConfig, XLSTMCfg
-    kw = {k: c[k] for k in (
-        "d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
-        "vocab_size", "pattern_reps", "rope_theta", "norm_eps",
-        "tie_embeddings", "dtype", "remat", "attn_chunk_q",
-        "attn_chunk_kv") if k in c}
-    kw["pattern"] = tuple(LayerSpec(m, f, window=w) for m, f, w in c["pattern"])
-    if "xlstm" in c:
-        kw["xlstm"] = XLSTMCfg(**c["xlstm"])
-    kw["subquadratic"] = all(m != "attn" for m, _, _ in c["pattern"])
-    return ModelConfig(name=c["name"], **kw)
+    """The program's ``ModelConfig`` built from a configuration file.
+
+    Every key that names a field of ``ModelConfig`` is passed on, nested
+    blocks (``moe``, ``mla``, ``mamba``, ``xlstm``) built through their
+    dataclass and ``pattern``, ``lead`` and ``tail`` as ``LayerSpec``s;
+    the keys in ``DESCRIPTIVE`` are left out, and any other key is an
+    error. ``subquadratic``, where the file does not give it, is true
+    when no layer's mixer is in ``QUADRATIC``."""
+    from repro.configs.base import ModelConfig
+    fields = _field_types(ModelConfig)
+    unknown = sorted(set(c) - set(fields) - set(DESCRIPTIVE))
+    if unknown:
+        raise ValueError(
+            f"configuration {c.get('name')!r}: {unknown} neither a field of "
+            f"ModelConfig nor a descriptive key {DESCRIPTIVE}")
+    kw = {k: _build(fields[k], v) for k, v in c.items() if k in fields}
+    if "subquadratic" not in kw:
+        specs = kw.get("lead", ()) + kw["pattern"] + kw.get("tail", ())
+        kw["subquadratic"] = all(s.mixer not in QUADRATIC for s in specs)
+    return ModelConfig(**kw)
 
 
 def job(cell: dict):

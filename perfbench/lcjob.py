@@ -30,7 +30,9 @@ The C step: the input of the window's last C step, x = w - lambda / mu
 (the weights, multipliers and mu that step was given), is kept, and the
 reference compresses it again (top-kappa by a full sort, k-means by
 Lloyd from quantiles) and is compared with Delta(Theta) as the program
-returned it, per task item:
+returned it, per task item (a k-means task's item is its whole leaf, or
+with ``stack_ndim`` n one index on the leaf's n leading axes, as the
+program's ``AsStacked`` view splits it; top-kappa's is all its leaves):
   * ``cstep_gap``: the worst item's distortion ||x - Delta||^2 over the
     reference's, less 1 (below 0 where the program found a better
     codebook than the reference);
@@ -98,16 +100,38 @@ def tokens_per_iteration(tr: dict) -> int:
 # ----------------------------------------------------------------------
 # the program
 # ----------------------------------------------------------------------
-def resolve_tasks(tr: dict, paths) -> list[dict]:
-    """The traffic's compression tasks resolved against parameter paths:
-    one entry per task as the program gets it. A ``per_leaf`` task is one
-    task per matching leaf (one codebook per matrix); otherwise k-means
-    gives one item per layer of the stacked leaves and top-kappa keeps
-    ``1 / kappa_divisor`` of all matching weights as one vector."""
+TASK_KEYS = {"kmeans": {"k", "iters", "stack_ndim"},
+             "topk": {"kappa_divisor"}}
+
+
+def resolve_tasks(tr: dict, leaves: dict) -> list[dict]:
+    """The traffic's compression tasks resolved against ``leaves``
+    (parameter path to array or shape): one entry per task as the program
+    gets it. A ``per_leaf`` task is one task per matching leaf; otherwise
+    top-kappa keeps ``1 / kappa_divisor`` of all matching weights as one
+    vector. A k-means task is ``per_leaf``; its ``stack_ndim`` leading
+    axes of each leaf (0 by default) index separate items, each with its
+    own codebook. A task the program cannot be given is an error."""
     out = []
     for i, t in enumerate(tr["tasks"]):
+        if t["scheme"] not in TASK_KEYS:
+            raise ValueError(f"task {i}: unknown scheme {t['scheme']!r}")
+        extra = set(t) - {"scheme", "pattern", "per_leaf"} - TASK_KEYS[t["scheme"]]
+        if extra:
+            raise ValueError(f"task {i}: keys {sorted(extra)} are not "
+                             f"keys of a {t['scheme']} task")
+        if t["scheme"] == "kmeans" and not t.get("per_leaf"):
+            raise ValueError(f"task {i}: a k-means task is per_leaf; its "
+                             f"items within a leaf are set by stack_ndim")
         rx = re.compile(t["pattern"])
-        match = sorted(p for p in paths if rx.search(p))
+        match = sorted(p for p in leaves if rx.search(p))
+        if not match:
+            raise ValueError(f"task {i}: {t['pattern']!r} matches no leaf")
+        n = t.get("stack_ndim", 0)
+        for p in match:
+            if n < 0 or (n and n >= len(leaves[p].shape)):
+                raise ValueError(f"task {i}: stack_ndim {n} leaves no item "
+                                 f"axis in {p} {tuple(leaves[p].shape)}")
         if t.get("per_leaf"):
             out += [dict(t, name=p, paths=[p]) for p in match]
         else:
@@ -127,17 +151,26 @@ def program_tasks(tr: dict, cfg) -> list:
     for t in resolve_tasks(tr, shapes):
         pattern = "^(" + "|".join(re.escape(p) for p in t["paths"]) + ")$"
         if t["scheme"] == "kmeans":
-            view = AsVector() if t.get("per_leaf") else AsStacked("vector")
+            n = t.get("stack_ndim", 0)
+            view = AsStacked("vector", stack_ndim=n) if n else AsVector()
             scheme = AdaptiveQuantization(k=t["k"], iters=t["iters"])
-        elif t["scheme"] == "topk":
+        else:
             total = sum(shapes[p].size for p in t["paths"])
             view = AsVector()
             scheme = ConstraintL0Pruning(
                 kappa=max(1, total // t["kappa_divisor"]))
-        else:
-            raise ValueError(t["scheme"])
         out.append(CompressionTask(t["name"], pattern, view, scheme))
     return out
+
+
+def model_config(c: dict):
+    """The program's ``ModelConfig`` of configuration file ``c``; the
+    feed draws token ids, so a model fed embeddings is refused."""
+    cfg = harness.model_config(c)
+    if cfg.input_mode != "tokens":
+        raise ValueError(f"configuration {cfg.name!r}: input_mode "
+                         f"{cfg.input_mode!r}; the LC job feeds token ids")
+    return cfg
 
 
 def build_trainer(cell: dict, seed: int, n_lc: int):
@@ -147,7 +180,7 @@ def build_trainer(cell: dict, seed: int, n_lc: int):
     from repro.runtime import LCTrainer, TrainerConfig
 
     tr = cell["traffic_file"]
-    cfg = harness.model_config(cell["config_file"])
+    cfg = model_config(cell["config_file"])
     lc = LCAlgorithm(program_tasks(tr, cfg),
                      exponential_mu_schedule(tr["mu0"], tr["mu_a"], n_lc))
     feed = Feed(seed, cfg.vocab_size, tr["batch"], tr["seq_len"])
@@ -257,13 +290,20 @@ def program_readings(cell: dict, seed: int, fault: str | None = None) -> dict:
 # ----------------------------------------------------------------------
 # the reference
 # ----------------------------------------------------------------------
+def item_rows(t: dict, w):
+    """Leaf ``w`` of k-means task ``t`` as one row per item: its
+    ``stack_ndim`` leading axes index the items."""
+    n = t.get("stack_ndim", 0)
+    return w.reshape(int(np.prod(w.shape[:n])), -1)
+
+
 def compress(t: dict, leaves: list, iters: int | None = None,
              dtype=None) -> list:
     """The reference's Delta(Theta) of task ``t`` over ``leaves`` (its
     paths' arrays, in order): top-kappa over the concatenated leaves, or
-    k-means per leaf (``per_leaf``) or per layer of each stacked leaf.
-    ``iters`` Lloyd steps (default: the task's); ``dtype`` computes the
-    k-means in a lower precision (the control)."""
+    k-means per item of its leaf (``item_rows``). ``iters`` Lloyd steps
+    (default: the task's); ``dtype`` computes the k-means in a lower
+    precision (the control)."""
     iters = t["iters"] if iters is None and "iters" in t else iters
     if t["scheme"] == "topk":
         vec = jnp.concatenate([w.ravel() for w in leaves])
@@ -274,14 +314,12 @@ def compress(t: dict, leaves: list, iters: int | None = None,
             out.append(kept[off:off + w.size].reshape(w.shape))
             off += w.size
         return out
-    if t["scheme"] == "kmeans":
-        km = jax.jit(refs.kmeans, static_argnums=(1, 2, 3, 4))
-        if t.get("per_leaf"):
-            return [km(w, t["k"], iters, 1 << 20, dtype).reshape(w.shape)
-                    for w in leaves]
-        return [jnp.stack([km(w[i], t["k"], iters, 1 << 20, dtype).reshape(
-            w.shape[1:]) for i in range(w.shape[0])]) for w in leaves]
-    raise ValueError(t["scheme"])
+    km = jax.jit(refs.kmeans, static_argnums=(1, 2, 3, 4))
+    (w,) = leaves
+    if not t.get("stack_ndim"):
+        return [km(w, t["k"], iters, 1 << 20, dtype).reshape(w.shape)]
+    return [jnp.stack([km(r, t["k"], iters, 1 << 20, dtype)
+                       for r in item_rows(t, w)]).reshape(w.shape)]
 
 
 def direct_compression(tasks: list[dict], flat: dict) -> dict:
@@ -296,6 +334,7 @@ def reference_readings(cell: dict, seed: int, mm: refs.MatMul) -> dict:
     """The reference's loss per step, first clipped gradient and
     parameter change over ``ref_steps`` AdamW steps."""
     c, tr = cell["config_file"], cell["traffic_file"]
+    model_config(c)
     ref = refs.model_reference(c["reference"][:-len(".ref.py")])
     ad = tr["adam"]
     with jax.default_matmul_precision("highest"):
@@ -420,12 +459,19 @@ def gaps(got: dict, ref: dict) -> dict:
 
 def items(t: dict, leaves: list) -> list:
     """The task's items as flat vectors: the concatenated leaves for
-    top-kappa, each leaf (``per_leaf``) or each layer for k-means."""
+    top-kappa, each row of ``item_rows`` for k-means."""
     if t["scheme"] == "topk":
         return [jnp.concatenate([jnp.ravel(w) for w in leaves])]
-    if t.get("per_leaf"):
-        return [jnp.ravel(w) for w in leaves]
-    return [jnp.ravel(w[i]) for w in leaves for i in range(w.shape[0])]
+    (w,) = leaves
+    return list(item_rows(t, w))
+
+
+def item_labels(t: dict, leaves: list) -> list[str]:
+    """``<task>[i,j]`` of each item, by its index on the leaf's
+    ``stack_ndim`` leading axes; ``<task>[0]`` for a whole leaf."""
+    n = t.get("stack_ndim", 0)
+    idx = np.ndindex(leaves[0].shape[:n]) if n else [(0,)]
+    return [f"{t['name']}[{','.join(map(str, i))}]" for i in idx]
 
 
 @jax.jit
@@ -470,10 +516,11 @@ def cstep_readings(tr: dict, x: dict, got: dict, prev: dict | None = None) -> di
         d_ref = [float(_distortion(a, b)) for a, b in
                  zip(x_it, items(t, compress(t, xs, ref_iters)))]
         g_it = items(t, [jnp.asarray(got[p]) for p in t["paths"]])
-        for j, (a, b) in enumerate(zip(x_it, g_it)):
+        for j, (a, b, label) in enumerate(zip(x_it, g_it,
+                                              item_labels(t, xs))):
             gap = float(_distortion(a, b)) / d_ref[j] - 1.0
             excess = used(t, b) - allowed(t, a.size)
-            out["cstep_items"].append([f"{t['name']}[{j}]", gap, excess])
+            out["cstep_items"].append([label, gap, excess])
             out["cstep_gap"] = max(out["cstep_gap"], gap)
             out["cstep_excess"] = max(out["cstep_excess"], excess)
         for r, fn in runs.items():

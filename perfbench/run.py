@@ -24,6 +24,31 @@ Everything a cell needs is found by name: the configuration in
 existing kind needs only new files there and an entry in
 ``BENCHMARK.json``.
 
+A configuration file is one JSON object. Each key that names a field of
+the program's ``ModelConfig`` (``src/repro/configs/base.py``) is passed
+on: ``name``, the widths and counts (``d_model``, ``n_heads``,
+``n_kv_heads``, ``head_dim``, ``d_ff``, ``vocab_size``,
+``pattern_reps``, ...), ``pattern``, ``lead`` and ``tail`` as lists of
+``[mixer, ffn, window]``, and the nested blocks ``moe``, ``mla``,
+``mamba`` and ``xlstm`` as objects of their dataclass's fields, where
+``null`` stays ``None`` (``"q_lora_rank": null``). ``subquadratic``, when
+left out, is true where no layer's mixer is ``attn`` or ``mla``. The
+descriptive keys ``source``, ``deployment``, ``reference`` (the
+``.ref.py`` beside it), ``published``, ``reduced``, ``why_reduced``,
+``assumed`` and ``sliding_window`` are not passed on; any other key is
+an error (``harness.model_config``).
+
+A traffic file of kind ``lc`` lists its compression ``tasks``
+(``lcjob.resolve_tasks``). Each has a ``scheme``, a ``pattern`` (a
+regular expression on parameter paths) and ``per_leaf`` (one task per
+matching leaf). A ``kmeans`` task is ``per_leaf`` and gives ``k``,
+``iters`` and optionally ``stack_ndim``: the number of leading axes of
+each leaf that index separate items, each with its own codebook (0, the
+default: the whole leaf is one item; 2 on a scanned expert leaf ``(L, E,
+d, f)``: one per layer and expert). A ``topk`` task gives
+``kappa_divisor`` and keeps that share of all its leaves as one vector.
+A task the program cannot be given is an error at load.
+
 Exits non-zero and prints no result when JAX finds no TPU, or fewer chips
 than the cell asks for.
 """
