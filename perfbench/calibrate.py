@@ -9,8 +9,10 @@ With ``--window`` each seed is a whole run of the cell with a window of
 one LC iteration (``lcjob.run``): it prints the L step's and the C step's
 numbers, and for the C step also those of its control and faults
 (``lcjob.cstep_readings``). Otherwise each seed prints the L step's
-numbers of the program, of each fault planted in it (``lcjob.FAULTS``)
-and, with ``--control``, of the reference in float8 put in its place.
+numbers of the program, of each fault planted in it (``lcjob.FAULTS``,
+``lcjob.CONFIG_FAULTS``; ``router_flipped`` and ``aux_dropped`` are for
+configurations with ``moe``) and, with ``--control``, of the reference in
+float8 put in its place.
 One JSON line a reading. The benchmark's own runs never run this.
 """
 from __future__ import annotations

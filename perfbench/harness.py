@@ -120,7 +120,9 @@ def model_config(c: dict):
     dataclass and ``pattern``, ``lead`` and ``tail`` as ``LayerSpec``s;
     the keys in ``DESCRIPTIVE`` are left out, and any other key is an
     error. ``subquadratic``, where the file does not give it, is true
-    when no layer's mixer is in ``QUADRATIC``."""
+    when no layer's mixer is in ``QUADRATIC``. A ``moe`` block states
+    its ``router_aux_weight``, and a weight of 0 needs an ``assumed``
+    entry of that name that says why."""
     from repro.configs.base import ModelConfig
     fields = _field_types(ModelConfig)
     unknown = sorted(set(c) - set(fields) - set(DESCRIPTIVE))
@@ -128,11 +130,30 @@ def model_config(c: dict):
         raise ValueError(
             f"configuration {c.get('name')!r}: {unknown} neither a field of "
             f"ModelConfig nor a descriptive key {DESCRIPTIVE}")
+    if c.get("moe") is not None:
+        _check_moe(c)
     kw = {k: _build(fields[k], v) for k, v in c.items() if k in fields}
     if "subquadratic" not in kw:
         specs = kw.get("lead", ()) + kw["pattern"] + kw.get("tail", ())
         kw["subquadratic"] = all(s.mixer not in QUADRATIC for s in specs)
     return ModelConfig(**kw)
+
+
+def _check_moe(c: dict) -> None:
+    """A file's ``moe`` block states the weight of the router's balance
+    term, since the program's default is no published value; a weight of
+    0, which leaves the term out of the loss, is explained in
+    ``assumed``."""
+    name = c.get("name")
+    if "router_aux_weight" not in c["moe"]:
+        raise ValueError(f"configuration {name!r}: moe does not state "
+                         f"router_aux_weight")
+    assumed = c.get("assumed")
+    why = assumed.get("router_aux_weight") if isinstance(assumed, dict) \
+        else None
+    if c["moe"]["router_aux_weight"] == 0 and not why:
+        raise ValueError(f"configuration {name!r}: router_aux_weight is 0 "
+                         f"and no assumed entry router_aux_weight says why")
 
 
 def job(cell: dict):
@@ -188,6 +209,38 @@ class Profiler:
             return tracing.Trace(tracing.load_dir(self.dir))
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
+
+
+class Programs:
+    """Keeps each program this process loads, compiled or read from the
+    compile cache, from its making until ``close()``: a TPU profile names
+    an op by its instruction alone, and the op's scope is in the metadata
+    of the program's compiled HLO (``tracing.Trace.add_hlo``). The texts
+    are printed only when asked for, after the window."""
+
+    def __init__(self):
+        from jax._src import compiler
+        self._compiler = compiler
+        self._load = compiler.compile_or_get_cached
+        self.executables = []
+
+        def keep(*a, **kw):
+            exe = self._load(*a, **kw)
+            self.executables.append(exe)
+            return exe
+        compiler.compile_or_get_cached = keep
+
+    def texts(self, names) -> list[str]:
+        """HLO text of each kept program whose name, as
+        ``tracing.program_name`` gives it, is in ``names``."""
+        import tracing
+        return [m.to_string() for exe in self.executables
+                for m in exe.hlo_modules()
+                if tracing.program_name(m.name) in names]
+
+    def close(self):
+        self._compiler.compile_or_get_cached = self._load
+        self.executables = []
 
 
 def per_layer(cell: dict, ctx: dict) -> dict:

@@ -25,7 +25,17 @@ same AdamW steps on the same token batches:
   * ``change_gap``: the parameters' change over the ``ref_steps`` steps,
     per leaf as for ``grad_gap``, leaving out leaves whose reference
     gradient is under a thousandth of the median leaf's (Adam moves them
-    by round-off alone).
+    by round-off alone);
+  * ``aux_gap`` (configurations with ``moe``): the largest relative gap
+    of a step's router balance term, unweighted and summed over the MoE
+    layers: the program's ``metrics["aux"]`` against the reference's at
+    its own parameters and the same batch. A broken router is caught here
+    on its own, where its share of the loss is too small for
+    ``loss_gap``.
+The reference's loss is the mean next-token NLL of its ``logits_row``
+over the batch, plus, where the ``.ref.py`` defines it, the batch-level
+``aux_loss(p, tokens, c, mm)`` (the weighted term the model adds; its
+gradient is not divided by the token count), plus the LC penalty.
 The C step: the input of the window's last C step, x = w - lambda / mu
 (the weights, multipliers and mu that step was given), is kept, and the
 reference compresses it again (top-kappa by a full sort, k-means by
@@ -165,22 +175,52 @@ def program_tasks(tr: dict, cfg) -> list:
 
 def model_config(c: dict):
     """The program's ``ModelConfig`` of configuration file ``c``; the
-    feed draws token ids, so a model fed embeddings is refused."""
+    feed draws token ids, so a model fed embeddings is refused. With
+    ``moe`` the ``.ref.py`` must define ``aux_loss``: at a non-zero
+    ``router_aux_weight`` the program adds the balance term to its loss,
+    and at any weight ``aux_gap`` reads it."""
     cfg = harness.model_config(c)
     if cfg.input_mode != "tokens":
         raise ValueError(f"configuration {cfg.name!r}: input_mode "
                          f"{cfg.input_mode!r}; the LC job feeds token ids")
+    if cfg.moe is not None:
+        if "reference" not in c:
+            raise ValueError(f"configuration {cfg.name!r}: moe needs a "
+                             f"reference (.ref.py) that defines aux_loss")
+        if not hasattr(reference(c), "aux_loss"):
+            raise ValueError(
+                f"configuration {cfg.name!r}: {c['reference']} defines no "
+                f"aux_loss; router_aux_weight is "
+                f"{cfg.moe.router_aux_weight}, and aux_gap reads the "
+                f"balance term at any weight")
     return cfg
 
 
-def build_trainer(cell: dict, seed: int, n_lc: int):
+def reference(c: dict):
+    """The plain reference module named by configuration file ``c``."""
+    return refs.model_reference(c["reference"][:-len(".ref.py")])
+
+
+def cell_config(cell: dict):
+    """``model_config`` of the cell's configuration; a cell whose
+    configuration has ``moe`` must give ``aux_gap`` in its limits."""
+    cfg = model_config(cell["config_file"])
+    if cfg.moe is not None and "aux_gap" not in cell["limits"]:
+        raise ValueError(f"perfbench/limits/{cell['name']}.json gives no "
+                         f"aux_gap; the configuration has moe")
+    return cfg
+
+
+def build_trainer(cell: dict, seed: int, n_lc: int, fault: str | None = None):
     from repro.core import LCAlgorithm, exponential_mu_schedule
     from repro.launch.mesh import make_debug_mesh
     from repro.optim import AdamW
     from repro.runtime import LCTrainer, TrainerConfig
 
     tr = cell["traffic_file"]
-    cfg = model_config(cell["config_file"])
+    cfg = cell_config(cell)
+    if fault in CONFIG_FAULTS:
+        cfg = CONFIG_FAULTS[fault](cfg)
     lc = LCAlgorithm(program_tasks(tr, cfg),
                      exponential_mu_schedule(tr["mu0"], tr["mu_a"], n_lc))
     feed = Feed(seed, cfg.vocab_size, tr["batch"], tr["seq_len"])
@@ -209,9 +249,12 @@ def change_norms(new: dict, old: dict) -> dict:
 
 def capture_readings(trainer, n_steps: int, b1: float) -> dict:
     """Wrap the trainer's step so that its first ``n_steps`` steps are
-    read: each step's loss, the first gradient (from Adam's first moment)
-    and the parameters' change. Returns the dict the readings fill."""
+    read: each step's loss (and, with ``moe``, its balance term), the
+    first gradient (from Adam's first moment) and the parameters' change.
+    Returns the dict the readings fill."""
     out = {"loss": []}
+    if trainer.cfg.moe is not None:
+        out["aux"] = []
     step_fn = trainer._one_step
 
     def one_step(state, step):
@@ -220,6 +263,8 @@ def capture_readings(trainer, n_steps: int, b1: float) -> dict:
         new, metrics = step_fn(state, step)
         if step < n_steps:
             out["loss"].append(float(metrics["loss"]))
+            if "aux" in out:
+                out["aux"].append(float(metrics["aux"]))
         if step == 0:
             out["grad"] = {p: v / (1.0 - b1)
                            for p, v in host_norms(new["opt"]["m"]).items()}
@@ -241,7 +286,35 @@ FAULTS = {
     # token instead of the next one
     "labels_altered": lambda step: lambda st, b: step(
         st, {"inputs": b["inputs"], "labels": b["inputs"]}),
+    # the router's choice altered: its k least likely experts
+    "router_flipped": lambda step: lambda st, b: _bottom_k(step, st, b),
 }
+
+
+def _bottom_k(step, state, batch):
+    """``step`` with ``jax.lax.top_k`` taking the k smallest values, for
+    as long as the call lasts: the first call traces the step."""
+    top_k = jax.lax.top_k
+
+    def bottom_k(x, k):
+        v, i = top_k(-x, k)
+        return -v, i
+    jax.lax.top_k = bottom_k
+    try:
+        return step(state, batch)
+    finally:
+        jax.lax.top_k = top_k
+
+
+def _aux_dropped(cfg):
+    import dataclasses
+    return dataclasses.replace(
+        cfg, moe=dataclasses.replace(cfg.moe, router_aux_weight=0.0))
+
+
+# faults planted in the configuration the program is built from: the
+# balance term left out of the program's loss
+CONFIG_FAULTS = {"aux_dropped": _aux_dropped}
 
 
 def _deltas_altered(step):
@@ -264,10 +337,11 @@ CSTEP_FAULTS = {
 
 def plant(trainer, fault: str | None):
     """Break the program's train step with one of ``FAULTS``, or its C
-    step with one of ``CSTEP_FAULTS``."""
+    step with one of ``CSTEP_FAULTS`` (``CONFIG_FAULTS`` are planted by
+    ``build_trainer``)."""
     if fault in FAULTS:
         trainer._train_step = FAULTS[fault](trainer._train_step)
-    elif fault is not None:
+    elif fault is not None and fault not in CONFIG_FAULTS:
         trainer.lc.c_step = CSTEP_FAULTS[fault](trainer.lc.c_step)
 
 
@@ -275,7 +349,7 @@ def program_readings(cell: dict, seed: int, fault: str | None = None) -> dict:
     """The readings of the program's first ``ref_steps`` steps, taken
     through the trainer's own step and feed, with no window."""
     tr = cell["traffic_file"]
-    trainer = build_trainer(cell, seed, 1)
+    trainer = build_trainer(cell, seed, 1, fault)
     plant(trainer, fault)
     readings = capture_readings(trainer, tr["ref_steps"], tr["adam"]["b1"])
     state = trainer.init_state(harness.seed_key(seed))
@@ -331,11 +405,13 @@ def direct_compression(tasks: list[dict], flat: dict) -> dict:
 
 
 def reference_readings(cell: dict, seed: int, mm: refs.MatMul) -> dict:
-    """The reference's loss per step, first clipped gradient and
-    parameter change over ``ref_steps`` AdamW steps."""
+    """The reference's loss per step (and, with ``aux_loss``, its
+    unweighted balance term), first clipped gradient and parameter change
+    over ``ref_steps`` AdamW steps."""
     c, tr = cell["config_file"], cell["traffic_file"]
-    model_config(c)
-    ref = refs.model_reference(c["reference"][:-len(".ref.py")])
+    cell_config(cell)
+    ref = reference(c)
+    aux_loss = getattr(ref, "aux_loss", None)
     ad = tr["adam"]
     with jax.default_matmul_precision("highest"):
         params = jax.jit(lambda k: ref.init_params(k, c))(harness.seed_key(seed))
@@ -349,16 +425,29 @@ def reference_readings(cell: dict, seed: int, mm: refs.MatMul) -> dict:
                 lambda t, l: refs.row_nll(ref.logits_row(p, t, c, mm), l))(
                     toks, labels))
         batch_vg = jax.jit(jax.value_and_grad(batch_nll))
+        if aux_loss is not None:
+            # the weighted term the model adds, with the file's weight; at
+            # weight 0 the unweighted one is read at weight 1
+            w = c["moe"]["router_aux_weight"]
+            aux_vg = jax.jit(jax.value_and_grad(
+                lambda p, toks: aux_loss(p, toks, c, mm)))
+            c1 = dict(c, moe=dict(c["moe"], router_aux_weight=1.0))
+            aux_at_one = jax.jit(lambda p, toks: aux_loss(p, toks, c1, mm))
 
         @jax.jit
-        def finish(p, g_sum, nll_sum, n_tok, a):
+        def finish(p, g_sum, nll_sum, n_tok, a, aux=None, aux_g=None):
             fp = refs.flatten(p)
             pen = sum(0.5 * mu * jnp.sum(jnp.square(fp[q] - a[q])) for q in a)
             fg = refs.flatten(jax.tree_util.tree_map(lambda g: g / n_tok,
                                                      g_sum))
+            loss = nll_sum / n_tok
+            if aux is not None:
+                fa = refs.flatten(aux_g)
+                fg = {q: fg[q] + fa[q] for q in fg}
+                loss = loss + aux
             for q in a:
                 fg[q] = fg[q] + mu * (fp[q] - a[q])
-            return nll_sum / n_tok + pen, refs.unflatten(fg)
+            return loss + pen, refs.unflatten(fg)
 
         @jax.jit
         def update(p, g, m, v, t):
@@ -371,11 +460,19 @@ def reference_readings(cell: dict, seed: int, mm: refs.MatMul) -> dict:
         m = jax.tree_util.tree_map(jnp.zeros_like, params)
         v = jax.tree_util.tree_map(jnp.zeros_like, params)
         out = {"loss": []}
+        if aux_loss is not None:
+            out["aux"] = []
         for s in range(tr["ref_steps"]):
             batch = feed.batch_at(s)
             nll_sum, g_sum = batch_vg(params, batch["inputs"], batch["labels"])
+            aux = aux_g = None
+            if aux_loss is not None:
+                aux, aux_g = aux_vg(params, batch["inputs"])
+                out["aux"].append(float(aux) / w if w else
+                                  float(aux_at_one(params, batch["inputs"])))
             loss, grads = finish(params, g_sum, nll_sum,
-                                 float(tr["batch"] * tr["seq_len"]), a)
+                                 float(tr["batch"] * tr["seq_len"]), a,
+                                 aux, aux_g)
             out["loss"].append(float(loss))
             params, m, v, clipped = update(params, grads, m, v,
                                            jnp.float32(s + 1))
@@ -447,14 +544,18 @@ def gaps(got: dict, ref: dict) -> dict:
     change = {p: abs(got["change"][p] - cr[p]) / max(cr[p], med_c)
               for p in moved}
     top = lambda d: [[p, d[p]] for p in sorted(d, key=d.get)[::-1][:4]]
-    return {"loss_gap": loss, "grad_gap": max(grad.values()),
-            "change_gap": max(change.values()),
-            "grad_gap_median": float(np.median(list(grad.values()))),
-            "change_gap_median": float(np.median(list(change.values()))),
-            "worst": {"grad": top(grad), "change": top(change)},
-            "step_loss_gaps": [abs(g - r) / abs(r) for g, r in
-                               zip(got["loss"], ref["loss"])],
-            "left_out": sorted(set(gr) - set(moved))}
+    out = {"loss_gap": loss, "grad_gap": max(grad.values()),
+           "change_gap": max(change.values()),
+           "grad_gap_median": float(np.median(list(grad.values()))),
+           "change_gap_median": float(np.median(list(change.values()))),
+           "worst": {"grad": top(grad), "change": top(change)},
+           "step_loss_gaps": [abs(g - r) / abs(r) for g, r in
+                              zip(got["loss"], ref["loss"])],
+           "left_out": sorted(set(gr) - set(moved))}
+    if "aux" in ref:
+        out["aux_gap"] = max(abs(g - r) / abs(r)
+                             for g, r in zip(got["aux"], ref["aux"]))
+    return out
 
 
 def items(t: dict, leaves: list) -> list:
@@ -531,7 +632,8 @@ def cstep_readings(tr: dict, x: dict, got: dict, prev: dict | None = None) -> di
     return out
 
 
-NUMBERS = ("loss_gap", "grad_gap", "change_gap", "cstep_gap", "cstep_excess")
+NUMBERS = ("loss_gap", "grad_gap", "change_gap", "aux_gap", "cstep_gap",
+           "cstep_excess")
 
 
 def judge(numbers: dict, limits: dict) -> tuple[bool, dict]:
@@ -557,7 +659,9 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t_start: float,
         device: dict, fault: str | None = None, calibrate: bool = False) -> dict:
     tr = cell["traffic_file"]
     n = n_iterations(tr, seconds)
-    trainer = build_trainer(cell, seed, 1 + n)
+    # a traced run keeps its programs, to name the trace's ops by scope
+    programs = harness.Programs() if trace else None
+    trainer = build_trainer(cell, seed, 1 + n, fault)
     plant(trainer, fault)
     sched = trainer.lc.mu_schedule
     readings = capture_readings(trainer, tr["ref_steps"], tr["adam"]["b1"])
@@ -611,6 +715,8 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t_start: float,
     dev["memory_peak_bytes"] = peak
     if trace:
         tr_ = prof.read()
+        tr_.add_hlo(programs.texts(tr_.programs()))
+        programs.close()
         dev["busy_s"] = tr_.busy_s()
         dev["window_s"] = tr_.window_s
         breakdown = tr_.breakdown()

@@ -78,6 +78,19 @@ def row_nll(logits, labels):
     return jnp.sum(lse - gold)
 
 
+def switch_balance(probs, chosen, n_experts: int):
+    """The Switch Transformer's load-balance term of one layer's routing,
+    ``E * sum_e f_e * P_e`` (Fedus et al., arXiv:2101.03961): over the T
+    tokens routed, ``f_e`` is the mean number of times a token chose
+    expert e and ``P_e`` the mean router probability of e. ``probs`` (T,
+    E), ``chosen`` (T, k) expert indices. The choice carries no gradient,
+    the probabilities do."""
+    hits = chosen[:, :, None] == jnp.arange(n_experts)[None, None, :]
+    f = jnp.mean(jnp.sum(hits.astype(F32), axis=1), axis=0)
+    p = jnp.mean(probs.astype(F32), axis=0)
+    return n_experts * jnp.sum(f * p)
+
+
 # ----------------------------------------------------------------------
 # compression: plain k-means (Lloyd) and top-κ by sort
 # ----------------------------------------------------------------------
@@ -195,6 +208,9 @@ def load_module(path: Path, name: str):
 
 
 def model_reference(config_name: str):
-    """The plain reference module kept beside a configuration's file."""
+    """The plain reference module kept beside a configuration's file
+    (``configs/<config_name>.ref.py``; a path that is absolute is taken
+    as it is)."""
+    name = Path(config_name).name
     return load_module(HERE / "configs" / f"{config_name}.ref.py",
-                       "ref_" + config_name.replace("-", "_").replace(".", "_"))
+                       "ref_" + name.replace("-", "_").replace(".", "_"))

@@ -21,8 +21,11 @@ Everything a cell needs is found by name: the configuration in
 ``traffic/<traffic>.json``, the limits of its comparison in
 ``limits/<workload>.json`` and each per-layer metric's reader in
 ``metrics/<metric>.py``. A cell of a new model or traffic mix of an
-existing kind needs only new files there and an entry in
-``BENCHMARK.json``.
+existing kind needs only new files there and entries in
+``BENCHMARK.json``: its own in ``workloads`` (and ``configs``), and its
+name appended to the ``workloads`` list of each metric it reports
+(``lc_tokens_per_s`` and the per-layer metrics that apply to it); it
+changes nothing else in an entry that is there.
 
 A configuration file is one JSON object. Each key that names a field of
 the program's ``ModelConfig`` (``src/repro/configs/base.py``) is passed
@@ -37,6 +40,32 @@ descriptive keys ``source``, ``deployment``, ``reference`` (the
 ``.ref.py`` beside it), ``published``, ``reduced``, ``why_reduced``,
 ``assumed`` and ``sliding_window`` are not passed on; any other key is
 an error (``harness.model_config``).
+
+The plain reference ``configs/<reference>`` (``reference`` names it, as
+``<config>.ref.py``) imports nothing of the program and defines, for a
+configuration file ``c`` (a dict) and ``mm`` (``refs.MatMul``, through
+which every matrix product goes):
+
+* ``init_params(key, c)``: the weights, drawn from ``key`` as the
+  program draws them, as nested dicts with the program's paths;
+* ``logits_row(p, tokens, c, mm)``: the logits (S, vocab) of one row of
+  tokens (S,);
+* ``matmul_params(c)``: the weights a token meets in matrix products;
+* ``mixer_flops_per_token(c, seq)``: the mixer's operations per token
+  beyond its weight products, at rows of ``seq`` tokens;
+* optionally ``aux_loss(p, tokens, c, mm)``: a term over the whole
+  batch ``tokens`` (B, S), already weighted, that the model adds to its
+  mean next-token loss, such as a router's balance term (its weight from
+  ``c``, never the program's default; ``refs.switch_balance`` is the
+  Switch term of one layer).
+
+A configuration with ``moe`` is held to more rules at load: its ``moe``
+states ``router_aux_weight``, a weight of 0 needs an ``assumed`` entry
+``router_aux_weight`` that says why, its ``.ref.py`` defines
+``aux_loss``, and the limits of each of its cells give ``aux_gap``: the
+largest relative gap of a step's unweighted balance term, the program's
+``metrics["aux"]`` against the reference's (``lcjob.py``'s docstring has
+every number ``correct`` compares).
 
 A traffic file of kind ``lc`` lists its compression ``tasks``
 (``lcjob.resolve_tasks``). Each has a ``scheme``, a ``pattern`` (a

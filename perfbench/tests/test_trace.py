@@ -20,8 +20,8 @@ D0, D1, HOST = "/device:TPU:0", "/device:TPU:1", "/host:CPU"
 OPS, MODS = tracing.OPS_LINE, tracing.MODULES_LINE
 
 
-def ev(plane, line, name, start, end):
-    return (plane, line, name, float(start), float(end))
+def ev(plane, line, name, start, end, *scope):
+    return (plane, line, name, float(start), float(end), *scope)
 
 
 def synthetic():
@@ -104,3 +104,95 @@ def test_recorded_trace():
     busy = sum(b - a for a, b in zip(edges, edges[1:])
                if any(s <= a and b <= e for s, e in ops))
     assert sum(e - s for s, e in t.busy_intervals(d0)) == pytest.approx(busy)
+
+
+# ----------------------------------------------------------------------
+# device time by scope
+# ----------------------------------------------------------------------
+REMAT = "jit(train_step)/transpose(jvp(blk))/rematted_computation"
+
+
+def scoped_events():
+    """A train step on two devices: a loop whose body runs two scoped
+    ops, an op after it, and ops that stick out of the window at both
+    ends; one op with no scope."""
+    return [
+        ev(HOST, "python3", "bench.window", 1000, 11000),
+        ev(D0, MODS, "jit_train_step(3)", 500, 11500),
+        ev(D0, OPS, "fusion.1", 500, 1200, "jit(train_step)/embed"),
+        ev(D0, OPS, "while.5", 1500, 4500, REMAT + "/while"),
+        ev(D0, OPS, "fusion.7", 1600, 2600, REMAT + "/dot_general"),
+        ev(D0, OPS, "fusion.8", 2700, 4400, REMAT + "/dot_general"),
+        ev(D0, OPS, "fusion.9", 4600, 5600, "jit(train_step)/add"),
+        ev(D0, OPS, "copy.2", 5600, 5800),
+        ev(D0, OPS, "fusion.3", 10500, 12000, "jit(train_step)/adamw"),
+        ev(D1, MODS, "jit_train_step(3)", 1900, 3100),
+        ev(D1, OPS, "while.5", 2000, 3000, REMAT + "/while"),
+        ev(D1, OPS, "fusion.7", 2100, 2900, REMAT + "/dot_general"),
+    ]
+
+
+def test_scoped_counts_nested_time_once():
+    t = tracing.Trace(scoped_events())
+    # the loop's 3000 ns hold its body's 2700 ns: counted once, one call
+    assert t.scoped(r"rematted_computation") == (1, pytest.approx(3000e-9))
+    assert t.scoped(r"rematted_computation/dot_general") == \
+        (2, pytest.approx(2700e-9))
+    # clipped to the window: [1000, 1200] + 3000 + 1000 + [10500, 11000]
+    assert t.scoped(r"^jit\(train_step\)") == (4, pytest.approx(4700e-9))
+    assert t.scoped(r"rematted_computation", device=D1) == \
+        (1, pytest.approx(1000e-9))
+    assert t.scoped(r"no_such_scope") == (0, 0)
+    # the ops by name are read as before: the loop's body counts again
+    assert t.ops(r"^(while|fusion)\.[578]$") == (3, pytest.approx(5700e-9))
+
+
+HLO = """HloModule jit_train_step, entry_computation_layout={(f32[4]{0})->f32[4]{0}}
+
+%body.1 (p.1: f32[4]) -> f32[4] {
+  %p.1 = f32[4]{0} parameter(0)
+  ROOT %fusion.7 = f32[4]{0} fusion(f32[4]{0} %p.1), kind=kLoop, calls=%fc, \
+metadata={op_name="jit(train_step)/transpose(jvp(blk))/rematted_computation/dot_general" \
+source_file="blk.py" source_line=3}
+}
+
+ENTRY %main.9 (a.1: f32[4]) -> f32[4] {
+  %a.1 = f32[4]{0} parameter(0)
+  %while.5 = f32[4]{0} while(f32[4]{0} %a.1), condition=%c.2, body=%body.1, \
+metadata={op_name="jit(train_step)/transpose(jvp(blk))/rematted_computation/while"}
+  ROOT %copy.2 = f32[4]{0} copy(f32[4]{0} %while.5)
+}
+"""
+
+
+def test_add_hlo_fills_scopes_from_the_program_text():
+    evs = [e[:5] for e in scoped_events()]
+    t = tracing.Trace(evs)
+    assert t.scoped(r"rematted_computation") == (0, 0)
+    # while.5 and fusion.7 on both devices by their own op_name
+    assert t.add_hlo([HLO]) == 4
+    assert t.scoped(r"rematted_computation") == (1, pytest.approx(3000e-9))
+    assert t.scoped(r"rematted_computation/dot_general") == \
+        (1, pytest.approx(1000e-9))
+    # an op with none takes the scope of the op it runs in (fusion.8 the
+    # loop's), and at the top of its program jit(train_step): copy.2 and
+    # the ops the text does not hold, clipped to the window
+    assert t.scoped(r"rematted_computation/while$") == \
+        (1, pytest.approx(3000e-9))
+    assert t.scoped(r"^jit\(train_step\)$") == (4, pytest.approx(1900e-9))
+
+
+def test_recorded_trace_reads_as_before():
+    """The recorded trace carries no scope: what ``ops``, ``top_ops`` and
+    ``breakdown`` read is what they read before scopes were kept."""
+    rec = json.loads(gzip.open(DATA / "trace_small.json.gz", "rt").read())
+    events = [tuple(e) for e in rec["events"]]
+    t = tracing.Trace(events)
+    assert t.breakdown() == {"device_ops": rec["expect"]["top_ops"],
+                             "idle_gaps": rec["expect"]["idle_gaps"]}
+    d0 = t.devices[0]
+    fus = [min(e, t.t1) - max(s, t.t0) for p, l, n, s, e in events
+           if p == d0 and l == OPS and n.startswith("fusion")
+           and e > t.t0 and s < t.t1]
+    assert t.ops(r"^fusion") == (len(fus), sum(fus) * 1e-9)
+    assert t.scoped(r".") == (0, 0)
