@@ -80,6 +80,12 @@ def blockwise_attention(q, k, v, q_positions, k_positions, *,
     q: (B, Sq, H, D); k, v: (B, Sk, KV, D); positions: (Sq,), (Sk,).
     Returns (B, Sq, H, D). Causal by construction of the position mask.
 
+    The gradient is FlashAttention-2's: the forward saves q, k, v, the
+    output and the per-row logsumexp, and the backward recomputes each
+    chunk pair's logits and probabilities (``_attention_bwd``), so no
+    (qc, kc) array is kept between the passes. Every op of both passes
+    runs under the ``blockwise_attention`` named scope.
+
     ``fused=True`` tags the computation as the fused flash-attention
     Pallas kernel (kernels/flash_attention — same math, VMEM-resident
     tiles) for the dry-run's fused-kernel byte accounting.
@@ -90,54 +96,153 @@ def blockwise_attention(q, k, v, q_positions, k_positions, *,
                 q, k, v, q_positions, k_positions, window=window,
                 q_chunk=q_chunk, kv_chunk=kv_chunk, scale=scale,
                 fused=False)
-    b, sq, h, d = q.shape
-    sk, kv = k.shape[1], k.shape[2]
-    dv = v.shape[-1]                      # may differ from d (MLA)
-    g = h // kv
+    d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
-    qc = min(q_chunk, sq)
-    kc = min(kv_chunk, sk)
-    assert sq % qc == 0 and sk % kc == 0, (sq, qc, sk, kc)
-    nq, nk = sq // qc, sk // kc
+    qc = min(q_chunk, q.shape[1])
+    kc = min(kv_chunk, k.shape[1])
+    assert q.shape[1] % qc == 0 and k.shape[1] % kc == 0, \
+        (q.shape[1], qc, k.shape[1], kc)
+    return _attention(window, qc, kc, scale, q, k, v, q_positions,
+                      k_positions)
 
-    # scan axes lead: (nq, B, qc, ...) / (nk, B, kc, ...)
-    qr = jnp.moveaxis(q.reshape(b, nq, qc, kv, g, d), 1, 0)
-    kr = jnp.moveaxis(k.reshape(b, nk, kc, kv, d), 1, 0)
-    vr = jnp.moveaxis(v.reshape(b, nk, kc, kv, dv), 1, 0)
-    qp = q_positions.reshape(nq, qc)
-    kp = k_positions.reshape(nk, kc)
 
-    def q_chunk_body(_, qi):
-        q_i, qp_i = qi                       # (B,qc,KV,G,D), (qc,)
-        m0 = jnp.full((b, kv, g, qc), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((b, kv, g, qc), jnp.float32)
-        a0 = jnp.zeros((b, kv, g, qc, dv), jnp.float32)
+def _chunk_q(x, qc):
+    """(B, Sq, KV, G, D) -> (nq, B, qc, KV, G, D): scan axis first."""
+    b, sq, kv, g, d = x.shape
+    return jnp.moveaxis(x.reshape(b, sq // qc, qc, kv, g, d), 1, 0)
 
-        def kv_chunk_body(carry, kj):
-            m, l, acc = carry
+
+def _chunk_kv(x, kc):
+    """(B, Sk, KV, D) -> (nk, B, kc, KV, D)."""
+    b, sk, kv, d = x.shape
+    return jnp.moveaxis(x.reshape(b, sk // kc, kc, kv, d), 1, 0)
+
+
+def _logits(q_i, k_j, qp_i, kp_j, window, scale):
+    """One chunk pair's masked logits (B, KV, G, qc, kc), f32."""
+    s = jnp.einsum("bqkgd,bckd->bkgqc", q_i, k_j,
+                   preferred_element_type=jnp.float32) * scale
+    mask = _mask(qp_i, kp_j, window)                      # (qc, kc)
+    return jnp.where(mask[None, None, None], s, NEG_INF)
+
+
+def _attention_fwd_scan(window, qc, kc, scale, q, k, v,
+                        q_positions, k_positions):
+    """The online-softmax scan: (output (B, Sq, H, Dv), logsumexp
+    (nq, B, KV, G, qc) f32)."""
+    with jax.named_scope("blockwise_attention"):
+        b, sq, h, d = q.shape
+        sk, kv = k.shape[1], k.shape[2]
+        dv = v.shape[-1]                      # may differ from d (MLA)
+        g = h // kv
+        qr = _chunk_q(q.reshape(b, sq, kv, g, d), qc)
+        kr, vr = _chunk_kv(k, kc), _chunk_kv(v, kc)
+        qp = q_positions.reshape(sq // qc, qc)
+        kp = k_positions.reshape(sk // kc, kc)
+
+        def q_chunk_body(_, qi):
+            q_i, qp_i = qi                       # (B,qc,KV,G,D), (qc,)
+            m0 = jnp.full((b, kv, g, qc), NEG_INF, jnp.float32)
+            l0 = jnp.zeros((b, kv, g, qc), jnp.float32)
+            a0 = jnp.zeros((b, kv, g, qc, dv), jnp.float32)
+
+            def kv_chunk_body(carry, kj):
+                m, l, acc = carry
+                k_j, v_j, kp_j = kj
+                s = _logits(q_i, k_j, qp_i, kp_j, window, scale)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                p = jnp.exp(s - m_new[..., None])
+                alpha = jnp.exp(m - m_new)
+                l_new = l * alpha + jnp.sum(p, axis=-1)
+                pv = jnp.einsum("bkgqc,bckd->bkgqd", p.astype(v_j.dtype),
+                                v_j, preferred_element_type=jnp.float32)
+                acc_new = acc * alpha[..., None] + pv
+                return (m_new, l_new, acc_new), None
+
+            (m, l, acc), _ = jax.lax.scan(
+                kv_chunk_body, (m0, l0, a0), (kr, vr, kp))
+            out = acc / jnp.maximum(l[..., None], 1e-30)   # (B,KV,G,qc,D)
+            return None, (out.astype(q.dtype), m + jnp.log(l))
+
+        _, (outs, lse) = jax.lax.scan(q_chunk_body, None, (qr, qp))
+        out = jnp.moveaxis(outs, 0, 1)                  # (B,nq,KV,G,qc,Dv)
+        out = jnp.moveaxis(out, 4, 2)                   # (B,nq,qc,KV,G,Dv)
+        return out.reshape(b, sq, h, dv), lse
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _attention(window, qc, kc, scale, q, k, v, q_positions, k_positions):
+    return _attention_fwd_scan(window, qc, kc, scale, q, k, v,
+                               q_positions, k_positions)[0]
+
+
+def _attention_fwd(window, qc, kc, scale, q, k, v, q_positions,
+                   k_positions):
+    out, lse = _attention_fwd_scan(window, qc, kc, scale, q, k, v,
+                                   q_positions, k_positions)
+    return out, (q, k, v, out, lse, q_positions, k_positions)
+
+
+def _attention_bwd(window, qc, kc, scale, res, d_out):
+    """FlashAttention-2's backward. Per chunk pair: p = exp(s - lse),
+    dV += pᵀ·dO, dS = p ⊙ (dO·Vᵀ - D) with D = rowsum(dO ⊙ O),
+    dQ += dS·K, dK += dSᵀ·Q. The outer scan runs over kv chunks and
+    carries dQ; the inner one runs over q chunks and carries the kv
+    chunk's dK and dV."""
+    with jax.named_scope("blockwise_attention"):
+        q, k, v, out, lse, q_positions, k_positions = res
+        b, sq, h, d = q.shape
+        sk, kv = k.shape[1], k.shape[2]
+        dv = v.shape[-1]
+        g = h // kv
+        nq = sq // qc
+        qr = _chunk_q(q.reshape(b, sq, kv, g, d), qc)
+        dor = _chunk_q(d_out.reshape(b, sq, kv, g, dv), qc)
+        # D (nq, B, KV, G, qc): the rows' dO·O, as lse is laid out
+        dsum = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
+                       axis=-1)
+        dsum = jnp.moveaxis(_chunk_q(dsum.reshape(b, sq, kv, g, 1), qc)
+                            [..., 0], 2, -1)
+        kr, vr = _chunk_kv(k, kc), _chunk_kv(v, kc)
+        qp = q_positions.reshape(nq, qc)
+        kp = k_positions.reshape(sk // kc, kc)
+
+        def kv_chunk_body(dq, kj):
             k_j, v_j, kp_j = kj
-            s = jnp.einsum("bqkgd,bckd->bkgqc", q_i, k_j,
-                           preferred_element_type=jnp.float32) * scale
-            mask = _mask(qp_i, kp_j, window)              # (qc, kc)
-            s = jnp.where(mask[None, None, None], s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[..., None])
-            alpha = jnp.exp(m - m_new)
-            l_new = l * alpha + jnp.sum(p, axis=-1)
-            pv = jnp.einsum("bkgqc,bckd->bkgqd", p.astype(v_j.dtype), v_j,
-                            preferred_element_type=jnp.float32)
-            acc_new = acc * alpha[..., None] + pv
-            return (m_new, l_new, acc_new), None
 
-        (m, l, acc), _ = jax.lax.scan(
-            kv_chunk_body, (m0, l0, a0), (kr, vr, kp))
-        out = acc / jnp.maximum(l[..., None], 1e-30)       # (B,KV,G,qc,D)
-        return None, out.astype(q.dtype)
+            def q_chunk_body(carry, qi):
+                dk_j, dv_j = carry
+                q_i, do_i, lse_i, d_i, qp_i = qi
+                s = _logits(q_i, k_j, qp_i, kp_j, window, scale)
+                p = jnp.exp(s - lse_i[..., None])
+                dv_j = dv_j + jnp.einsum(
+                    "bkgqc,bqkgd->bckd", p.astype(v_j.dtype), do_i,
+                    preferred_element_type=jnp.float32)
+                dp = jnp.einsum("bqkgd,bckd->bkgqc", do_i, v_j,
+                                preferred_element_type=jnp.float32)
+                ds = (p * (dp - d_i[..., None])).astype(q_i.dtype)
+                dk_j = dk_j + jnp.einsum(
+                    "bkgqc,bqkgd->bckd", ds, q_i,
+                    preferred_element_type=jnp.float32) * scale
+                dq_i = jnp.einsum("bkgqc,bckd->bqkgd", ds, k_j,
+                                  preferred_element_type=jnp.float32) * scale
+                return (dk_j, dv_j), dq_i
 
-    _, outs = jax.lax.scan(q_chunk_body, None, (qr, qp))   # (nq,B,KV,G,qc,Dv)
-    out = jnp.moveaxis(outs, 0, 1)                          # (B,nq,KV,G,qc,Dv)
-    out = jnp.moveaxis(out, 4, 2)                           # (B,nq,qc,KV,G,Dv)
-    return out.reshape(b, sq, h, dv)
+            zero = (jnp.zeros((b, kc, kv, d), jnp.float32),
+                    jnp.zeros((b, kc, kv, dv), jnp.float32))
+            (dk_j, dv_j), dq_j = jax.lax.scan(
+                q_chunk_body, zero, (qr, dor, lse, dsum, qp))
+            return dq + dq_j, (dk_j.astype(k.dtype), dv_j.astype(v.dtype))
+
+        dq0 = jnp.zeros(qr.shape, jnp.float32)
+        dq, (dk, dv_) = jax.lax.scan(kv_chunk_body, dq0, (kr, vr, kp))
+        dq = jnp.moveaxis(dq, 0, 1).reshape(b, sq, h, d).astype(q.dtype)
+        dk = jnp.moveaxis(dk, 0, 1).reshape(b, sk, kv, d)
+        dv_ = jnp.moveaxis(dv_, 0, 1).reshape(b, sk, kv, dv)
+        return dq, dk, dv_, None, None
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 def attn_forward(params, x, cfg, spec, positions, return_cache=False):

@@ -11,6 +11,7 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
 from functools import partial
 
 import jax
@@ -25,6 +26,7 @@ from repro.kernels import dispatch
 from repro.kernels.kmeans.kmeans import kmeans_assign_moments_batched
 from repro.kernels.prune.prune import count_above_batched, mask_apply_batched
 from repro.kernels.quant_matmul.quant_matmul import quant_matmul_packed
+from repro.models.attention import blockwise_attention
 
 D_MODEL, D_UP = 768, 1536          # xlstm-125m: d_model, mLSTM inner width
 ITEMS = 10                         # one packed k-means group: 5 tasks x 2
@@ -32,6 +34,7 @@ ITEM = D_MODEL * D_UP              # one stacked layer's projection, flat
 K = 16                             # 4-bit codebook
 SLOTS = 8                          # decode rows, padded to one sublane tile
 MLP_GROUP = (3, 3072 * 8192)       # phi3-mini's w_gate/w_up/w_down group
+PHI3_QKV = (1, 2048, 32, 96)       # phi3-mini's q, k, v at batch 1 x 2048
 
 
 @pytest.fixture(scope="module")
@@ -133,3 +136,32 @@ def test_kmeans_decompress_compiles_without_gather(one_chip):
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
     assert cost["optimal_seconds"] < 0.02
+
+
+def test_attention_backward_keeps_no_chunk_pair(one_chip):
+    """``jax.grad`` of a checkpointed ``blockwise_attention`` at phi3-mini's
+    shapes and chunks of 1024: with the scan's own autodiff it needs
+    3.39e9 bytes of temporaries, since each (1024, 1024) chunk pair's
+    logits, probabilities and mask are kept for the backward; the flash
+    backward keeps O(S·H) and recomputes them. Every loop that carries
+    floating-point state runs under the ``blockwise_attention`` scope."""
+    s = PHI3_QKV[1]
+
+    def loss(q, k, v):
+        pos = jnp.arange(s, dtype=jnp.int32)
+        attend = jax.checkpoint(lambda q, k, v: blockwise_attention(
+            q, k, v, pos, pos, window=s, q_chunk=1024, kv_chunk=1024))
+        return jnp.sum(attend(q, k, v).astype(jnp.float32))
+
+    arg = jax.ShapeDtypeStruct(PHI3_QKV, jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(arg, arg, arg) \
+        .compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    loops = re.findall(r"^\s*%?\S+ = (\(.*?\)) while\(.*"
+                       r'op_name="([^"]*)"', compiled.as_text(), re.M)
+    attention = [name for state, name in loops
+                 if re.search(r"\b(bf16|f32)\[", state)]
+    # forward and backward, each an outer loop over chunks and an inner one
+    assert len(attention) >= 4
+    assert all("blockwise_attention" in name for name in attention), \
+        attention
