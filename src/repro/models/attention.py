@@ -201,8 +201,7 @@ def _attention_bwd(window, qc, kc, scale, res, d_out):
         # D (nq, B, KV, G, qc): the rows' dO·O, as lse is laid out
         dsum = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
                        axis=-1)
-        dsum = jnp.moveaxis(_chunk_q(dsum.reshape(b, sq, kv, g, 1), qc)
-                            [..., 0], 2, -1)
+        dsum = dsum.reshape(b, nq, qc, kv, g).transpose(1, 0, 3, 4, 2)
         kr, vr = _chunk_kv(k, kc), _chunk_kv(v, kc)
         qp = q_positions.reshape(nq, qc)
         kp = k_positions.reshape(sk // kc, kc)

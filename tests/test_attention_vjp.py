@@ -9,14 +9,8 @@ import numpy as np
 import pytest
 
 from repro.models.attention import NEG_INF, _mask, blockwise_attention
+from test_models import ATTN_GRID as GRID
 from test_models import KEY, _naive_attn
-
-GRID = [
-    (2, 64, 4, 2, 16, 0, 16, 16),
-    (1, 128, 8, 8, 32, 24, 32, 16),
-    (3, 96, 6, 3, 16, 7, 32, 32),
-    (2, 32, 2, 1, 8, 0, 32, 8),
-]
 
 
 def _scan_attention(q, k, v, q_positions, k_positions, *, window=0,

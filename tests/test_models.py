@@ -121,12 +121,15 @@ def _naive_attn(q, k, v, window=0):
     return jnp.moveaxis(o, 3, 1).reshape(b, s, h, d)
 
 
-@pytest.mark.parametrize("b,s,h,kvh,d,w,qc,kc", [
+ATTN_GRID = [
     (2, 64, 4, 2, 16, 0, 16, 16),
     (1, 128, 8, 8, 32, 24, 32, 16),
     (3, 96, 6, 3, 16, 7, 32, 32),
     (2, 32, 2, 1, 8, 0, 32, 8),
-])
+]
+
+
+@pytest.mark.parametrize("b,s,h,kvh,d,w,qc,kc", ATTN_GRID)
 def test_blockwise_attention_matches_naive(b, s, h, kvh, d, w, qc, kc):
     kq, kk, kv_ = jax.random.split(KEY, 3)
     q = jax.random.normal(kq, (b, s, h, d))
